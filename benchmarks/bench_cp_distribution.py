@@ -30,6 +30,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import context_parallel as cp
 from repro.data.synthetic import random_multimodal_bits
@@ -105,7 +106,8 @@ def cp_fwd_bwd(smoke: bool = False):
     q = jax.random.normal(key, (B, T, H, hd), jnp.float32)
     bits = jnp.asarray(bits_np)[None]
     pos = jnp.asarray(pos_np)[None]
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     iters = 1 if smoke else 2
     if os.path.exists(CP_BWD_JSON):
         os.remove(CP_BWD_JSON)

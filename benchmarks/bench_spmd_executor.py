@@ -91,6 +91,9 @@ def run(smoke: bool = False):
                          M=4 if smoke else 8)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    # the child checks CPU virtual-device behaviour; it must not
+    # contend for an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=1200,

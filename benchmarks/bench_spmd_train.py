@@ -121,6 +121,9 @@ def _child(code: str, n_devices: int):
     env = dict(os.environ)
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={n_devices}"
+    # the child checks CPU virtual-device behaviour; it must not
+    # contend for an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=1200,

@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import bam, context_parallel as cp, distribution as dist
 from repro.data.synthetic import random_multimodal_bits
@@ -44,7 +45,8 @@ def main():
     plan = dist.plan_tokens(bits_np, pos_np, G, block_size=16, method="lpt")
     perm = cp.plan_permutation(plan, T)
     inv = cp.invert_perm(perm)
-    mesh = jax.make_mesh((G,), ("cp",))
+    mesh = jax.make_mesh((G,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     args = [jnp.take(a, perm, axis=1) for a in (q, k, v)]
     bp = jnp.take(bits, perm, axis=1)
     pp_ = jnp.take(pos, perm, axis=1)

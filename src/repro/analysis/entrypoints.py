@@ -80,9 +80,11 @@ def _attn_grad_jaxpr(impl: str):
 def _cp_grad_jaxpr(method: str, impl: str):
     import jax
     import jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro.core import context_parallel as cp
     q, bits, pos = _attention_case()
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
 
     def loss(q, k, v):
         return jnp.sum(cp.cp_attention(

@@ -44,6 +44,12 @@ Small-case dynamic rules (numpy-only, no kernel launch):
   the flat KV view is exactly page-padded, and
   ``paged_decode_attention`` rejects operands whose shapes disagree
   with the pool before any kernel is built.
+* ``tpu-block-tiling`` — Mosaic's tiling rule, checked on the CPU where
+  no TPU compiler need be present: in every ``pallas_call`` the kernel
+  wrappers trace at LLM-S widths, the last two dimensions of each VMEM
+  block are multiples of (8, 128) or equal to the array's. Interpret
+  mode accepts any block, so only this rule (or a compile for the chip)
+  catches a layout the chip refuses.
 """
 from __future__ import annotations
 
@@ -54,6 +60,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .findings import Finding, finding, register_rule
+from .jaxprlint import iter_jaxprs
 
 register_rule("blockspec-index-arity", "kernellint",
               "BlockSpec index maps take grid-rank (+ scalar-prefetch) "
@@ -75,6 +82,12 @@ register_rule("decode-grid-coverage", "kernellint",
 register_rule("page-grid-divisibility", "kernellint",
               "page-table capacity, pool shapes, and the decode "
               "kernel's page blocks agree on page_size")
+register_rule("tpu-block-tiling", "kernellint",
+              "the last two dimensions of every Pallas block are "
+              "multiples of (8, 128) or equal to the array's")
+
+#: Mosaic's minimum tile: (sublanes, lanes) of a 32-bit vector register
+TPU_TILE = (8, 128)
 
 KERNELS_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "kernels")
@@ -468,7 +481,7 @@ def check_page_divisibility(
     # any pallas_call is built
     ps = 4
     q = jnp.zeros((1, 2, 8))
-    pages = jnp.zeros((3, ps, 2, 8))
+    pages = jnp.zeros((3, 2, ps, 8))
     bits_ok = jnp.zeros((3, ps), jnp.uint32)
     pos_ok = jnp.zeros((3, ps), jnp.int32)
     steps = tuple(jnp.zeros(2, jnp.int32) for _ in range(5))
@@ -495,6 +508,86 @@ def check_page_divisibility(
     return out
 
 
+def _block_dim(d) -> Optional[int]:
+    """A BlockMapping dimension as an int; None for a squeezed one."""
+    size = getattr(d, "block_size", d)
+    return size if isinstance(size, int) else None
+
+
+def tiling_findings(jaxpr: Any, location: str) -> List[Finding]:
+    """``tpu-block-tiling`` over every ``pallas_call`` in ``jaxpr``
+    (nested jaxprs included): each VMEM block's last two dimensions must
+    be multiples of :data:`TPU_TILE` or equal to the array's."""
+    out: List[Finding] = []
+    pallas_calls = [eqn for sub in iter_jaxprs(jaxpr) for eqn in sub.eqns
+                    if eqn.primitive.name == "pallas_call"]
+    for eqn in pallas_calls:
+        name = eqn.params.get("name_and_src_info", "pallas_call")
+        for bm in eqn.params["grid_mapping"].block_mappings:
+            space = getattr(bm.block_aval, "memory_space", None)
+            if space is not None and "SMEM" in str(space).upper():
+                continue
+            block = [_block_dim(d) for d in bm.block_shape]
+            array = list(bm.array_aval.shape)
+            for axis, tile in zip((-1, -2), (TPU_TILE[1], TPU_TILE[0])):
+                if len(block) < -axis:
+                    continue
+                b, a = block[axis], array[axis]
+                if b is None or b == a or b % tile == 0:
+                    continue
+                out.append(finding(
+                    "tpu-block-tiling", f"{location}: {name} {bm.origin}",
+                    f"block {tuple(block)} on array {tuple(array)}: "
+                    f"dimension {axis} is {b}, neither a multiple of "
+                    f"{tile} nor the array's {a} — Mosaic refuses it"))
+    return out
+
+
+def check_tpu_block_tiling(B: int = 1, T: int = 256, H: int = 16,
+                           Hkv: int = 4, hd: int = 128,
+                           page_size: int = 16) -> List[Finding]:
+    """Trace the training kernels (forward in every return mode, dense
+    and compacted grids, fused backward) and the paged decode kernel at
+    LLM-S head widths, and apply ``tpu-block-tiling`` to every
+    ``pallas_call``. Tracing builds no kernel, so it runs on the CPU."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import bam
+    from repro.kernels.bam_attention import (bam_flash_attention,
+                                             bam_flash_attention_bwd)
+    from repro.kernels.paged_decode import paged_decode_attention
+    S = jax.ShapeDtypeStruct
+    q, kv = S((B, T, H, hd), jnp.bfloat16), S((B, T, Hkv, hd), jnp.bfloat16)
+    bits, pos = S((B, T), jnp.uint32), S((B, T), jnp.int32)
+    lse = S((B, H, T), jnp.float32)
+    sb, sp = bam.build_sample_bits([("text", 0, T // 4), ("mod", 1, T // 2),
+                                    ("text", 0, T // 4)], T)
+    bm = bam.build_block_map(sb, sb, sp, sp, 128, 128)
+    cases = []
+    for label, grid in (("dense", None), ("compacted", bm)):
+        for mode in ("out", "residual", "stats"):
+            cases.append((f"bam_flash_attention {mode} {label}",
+                          lambda *a, m=mode, g=grid: bam_flash_attention(
+                              *a, return_mode=m, block_map=g),
+                          (q, kv, kv, bits, bits, pos, pos)))
+        cases.append((f"bam_flash_attention_bwd {label}",
+                      lambda *a, g=grid: bam_flash_attention_bwd(
+                          *a, block_map=g),
+                      (q, kv, kv, q, q, lse, bits, bits, pos, pos)))
+    P, Bd = 8, 4
+    pages = S((P, Hkv, page_size, hd), jnp.bfloat16)
+    steps = tuple(S((16,), jnp.int32) for _ in range(5))
+    cases.append(("paged_decode_attention", paged_decode_attention,
+                  (S((Bd, H, hd), jnp.bfloat16), pages, pages,
+                   S((Bd, 1), jnp.uint32), S((Bd, 1), jnp.int32),
+                   S((P, page_size), jnp.uint32),
+                   S((P, page_size), jnp.int32), steps)))
+    out: List[Finding] = []
+    for label, fn, args in cases:
+        out += tiling_findings(jax.make_jaxpr(fn)(*args), label)
+    return out
+
+
 def lint_kernels(path: Optional[str] = None) -> List[Finding]:
     """All kernellint rules: AST rules over every ``.py`` under
     ``path`` (default: ``src/repro/kernels``) + the dynamic
@@ -509,4 +602,5 @@ def lint_kernels(path: Optional[str] = None) -> List[Finding]:
     out += check_block_divisibility()
     out += check_decode_grid_coverage()
     out += check_page_divisibility()
+    out += check_tpu_block_tiling()
     return out

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import bam
 from repro.core.distribution import Plan
@@ -425,10 +424,10 @@ def cp_attention(mesh, axis_name: str, q, k, v, q_bits, kv_bits, q_pos,
                            block_q=block_q, block_k=block_k)
     tok = P(None, axis_name)
     tok3 = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(tok3, tok3, tok3, tok, tok, tok, tok),
-        out_specs=tok3, check_rep=False,
+        out_specs=tok3, check_vma=False,
     )(q, k, v, q_bits, kv_bits, q_pos, kv_pos)
 
 

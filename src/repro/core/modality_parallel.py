@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -104,11 +103,11 @@ def pipeline_forward(mesh: Mesh, axis_name: str, stage_fn: Callable,
 
     spec_params = jax.tree.map(
         lambda a: P(axis_name, *([None] * (a.ndim - 1))), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P(*([None] * microbatches.ndim))),
         out_specs=P(*([None] * microbatches.ndim)),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, microbatches)
 
 

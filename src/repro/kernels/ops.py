@@ -204,5 +204,4 @@ def bam_attention_stats(q, k, v, q_bits, kv_bits, q_pos=None, kv_pos=None, *,
         padded[5], padded[6], softcap=softcap, window=window,
         block_q=block_q, block_k=block_k, return_mode="stats",
         block_map=block_map, interpret=(impl == "bam_interpret"))
-    acc = jnp.einsum("bqhd->bhqd", acc)
     return acc[:, :, :Tq], m[:, :, :Tq], l[:, :, :Tq]

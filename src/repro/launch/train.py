@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m \
         --steps 300 --seq 128 --batch 4 [--reduced] [--mllm valm] \
+        [--llm-size S] [--vision-size S] [--audio-size S] \
         [--ckpt-dir ckpts/run0] [--ckpt-every 50] [--resume] \
         [--fault-plan faults.json] [--log-every 10]
 
@@ -9,7 +10,9 @@ Two modes:
   * LM mode (``--arch``): any registered architecture; synthetic LM
     stream (repro.data.synthetic.TextLMDataset).
   * MLLM mode (``--mllm vlm|alm|valm``): the Cornstarch path — frozen
-    encoders + LLM, trainable projectors, multimodal batches; the
+    encoders + LLM, trainable projectors, multimodal batches, with each
+    module at a Table-1 size (``--llm-size`` / ``--vision-size`` /
+    ``--audio-size``, S|M|L); the
     frozen mask drives both stop_gradient and optimizer masking. The
     parallelization decision is a typed ``MLLMParallelPlan``
     (repro.parallel): load a cached one with ``--plan plan.json``, or
@@ -37,6 +40,8 @@ device loss) against the run — the chaos-testing entry point.
 
 Runs on whatever devices exist (data-parallel over the host mesh when
 more than one); this is the driver the smoke/e2e examples call into.
+``main`` keeps JAX's persistent compilation cache where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -51,6 +56,22 @@ from repro.data.synthetic import MultimodalDataset, TextLMDataset
 from repro.models import api
 from repro.optim import optimizer as opt
 from repro.training import steps
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX already reads it and nothing is set here; otherwise the cache
+    lives at the fixed ``<repo>/.jax_cache`` (its path is part of the
+    cache key, so it must not move between runs)."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _run_resilient(args, loss_fn, params, ocfg, *, frozen_mask=None,
@@ -124,7 +145,7 @@ def _run_resilient(args, loss_fn, params, ocfg, *, frozen_mask=None,
     if manager is not None:
         trainer.save_checkpoint()
         print(f"saved checkpoint to {manager.latest()}")
-    n_params = sum(x.size for x in jax.tree.leaves(params))
+    n_params = sum(x.size for x in jax.tree.leaves(trainer.params))
     losses = [v for _, v in sorted(res["losses"].items())]
     if res["rollbacks"] or res["skipped"]:
         print(f"resilience: {res['skipped']} skipped step(s), "
@@ -135,7 +156,8 @@ def _run_resilient(args, loss_fn, params, ocfg, *, frozen_mask=None,
           f"({took / done:.2f}s/step)")
     return {"params": n_params, "first_loss": losses[0],
             "last_loss": losses[-1], "losses": losses,
-            "resilience": res}
+            "step_seconds": res["step_seconds"],
+            "final_params": trainer.params, "resilience": res}
 
 
 def train_lm(args) -> dict:
@@ -253,30 +275,40 @@ def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
     """
     import json
 
+    from jax.sharding import NamedSharding, PartitionSpec
+
     from repro.parallel.spmd import build_spmd_runner, mesh_from_plan
     from repro.resilience.monitor import init_health
 
     D = int(executor["schedule"]["num_devices"])
     if len(jax.devices()) < D:
-        raise SystemExit(
-            f"--spmd needs {D} devices for this plan but the "
-            f"process has {len(jax.devices())}; relaunch with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={D}")
+        msg = (f"--spmd needs {D} devices for this plan but the process "
+               f"has {len(jax.devices())}")
+        if jax.default_backend() == "cpu":     # forced host devices
+            msg += (f"; relaunch with XLA_FLAGS=--xla_force_host_"
+                    f"platform_device_count={D}")
+        raise SystemExit(msg)
     bundle = executor["stage_bundle"]
     M = int(plan.schedule.num_microbatches)
     if args.batch % M != 0:
         raise SystemExit(
             f"--spmd needs --batch divisible by the plan's "
             f"{M} microbatches, got --batch {args.batch}")
+    mesh = mesh_from_plan(plan, mllm, D)
+    print("pipeline ranks -> devices: " + ", ".join(
+        f"{r}:{d}" for r, d in enumerate(mesh.devices.flat)))
     runner = build_spmd_runner(
         bundle.stage_fns, executor["sim_graph"], executor["schedule"],
-        mesh=mesh_from_plan(plan, mllm, D),
+        mesh=mesh,
         microbatch_loss=bundle.microbatch_loss,
         program=executor["spmd_program"],
         trainable=list(bundle.trainable))
 
     params = mllm.init(jax.random.PRNGKey(args.seed))
-    stage_params = bundle.partition(params)
+    # replicated over the pipeline mesh up front, where every step's
+    # outputs land: inputs placed elsewhere would recompile step two
+    stage_params = jax.device_put(bundle.partition(params),
+                                  NamedSharding(mesh, PartitionSpec()))
     frozen_mask = bundle.frozen_masks(stage_params)
     ocfg = opt.AdamWConfig(lr=args.lr, warmup_steps=min(50, args.steps // 10
                                                         or 1),
@@ -324,10 +356,16 @@ def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
                           convert_checkpoint=convert_checkpoint)
 
 
-def train_mllm(args) -> dict:
-    from repro.models.mllm import build_paper_mllm
-    mllm = build_paper_mllm(args.mllm, reduced=args.reduced,
-                            text_len=args.seq)
+def train_mllm(args, mllm=None) -> dict:
+    """MLLM-mode training. ``mllm`` is the ``MultimodalModule`` to train;
+    by default the paper's ``args.mllm`` composition at the sizes the
+    arguments name."""
+    if mllm is None:
+        from repro.models.mllm import build_paper_mllm
+        mllm = build_paper_mllm(args.mllm, llm_size=args.llm_size,
+                                vision_size=args.vision_size,
+                                audio_size=args.audio_size,
+                                reduced=args.reduced, text_len=args.seq)
     if args.train_llm:
         # the paper's ft1 fine-tune: frozen encoders, trainable LLM —
         # the scenario where zero-bubble W passes have work to defer
@@ -391,7 +429,7 @@ def train_mllm(args) -> dict:
                           convert_checkpoint=convert_checkpoint)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--mllm", default=None, choices=[None, "vlm", "alm",
@@ -403,6 +441,12 @@ def main(argv=None):
     ap.add_argument("--vocab", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--llm-size", default="M", choices=["S", "M", "L"],
+                    help="MLLM mode: Table-1 size of the LLM")
+    ap.add_argument("--vision-size", default="S", choices=["S", "M", "L"],
+                    help="MLLM mode: Table-1 size of the vision encoder")
+    ap.add_argument("--audio-size", default="S", choices=["S", "M", "L"],
+                    help="MLLM mode: Table-1 size of the audio encoder")
     ap.add_argument("--log-every", type=int, default=10)
     # fault tolerance (repro.resilience)
     ap.add_argument("--ckpt-dir", default=None,
@@ -446,6 +490,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if (args.arch is None) == (args.mllm is None):
         raise SystemExit("pass exactly one of --arch / --mllm")
+    return args
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compilation_cache()
     res = train_mllm(args) if args.mllm else train_lm(args)
     print(f"done: {res['params']:,} params, "
           f"loss {res['first_loss']:.3f} -> {res['last_loss']:.3f}")

@@ -174,7 +174,6 @@ def _shardmap_dispatch(lp, h, w, idx, cfg: ModelConfig, mesh, dp_axes):
     only tokens routed to them from its (model-replicated) activation
     shard; one psum over ``model`` combines the outputs. Per-layer
     collective traffic drops to one [B/dp, T, d] all-reduce."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -224,10 +223,10 @@ def _shardmap_dispatch(lp, h, w, idx, cfg: ModelConfig, mesh, dp_axes):
     dp = P(dp_axes, None, None)
     ep = P("model", None, None)
     tk = P(dp_axes, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(dp, ep, ep, ep, tk, tk, tk, tk, tk),
-        out_specs=dp, check_rep=False,
+        out_specs=dp, check_vma=False,
     )(h, lp["w_gate"], lp["w_up"], lp["w_down"], idx_f, slot_c, keep_cap,
       tok_f, w_f)
 

@@ -60,7 +60,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.schedule.graph import PipelineGraph
 from repro.core.schedule.simulator import Item, item_id
@@ -230,11 +229,14 @@ def default_mesh(num_devices: int, axis_name: str = "pp",
     with the XLA_FLAGS hint when the process has too few."""
     devs = list(devices if devices is not None else jax.devices())
     if len(devs) < num_devices:
+        hint = "run on a larger mesh"
+        if devs and devs[0].platform == "cpu":
+            hint = (f"set XLA_FLAGS=--xla_force_host_platform_"
+                    f"device_count={num_devices} (before importing jax) "
+                    f"or " + hint)
         raise ValueError(
             f"SPMD program needs {num_devices} devices but the process "
-            f"has {len(devs)}; set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={num_devices} (before importing jax) or run "
-            f"on a larger mesh")
+            f"has {len(devs)}; {hint}")
     return Mesh(np.array(devs[:num_devices]), (axis_name,))
 
 
@@ -626,12 +628,12 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 lambda a: P(axis_name, *([None] * (a.ndim - 1))),
                 local_params)
             grads_spec = spec_p
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec_p, P(*([None] * mbs.ndim))),
             out_specs=(P(*([None] * mbs.ndim)), P(), grads_spec,
                        P(axis_name, None), P(axis_name, None)),
-            check_rep=False,
+            check_vma=False,
         )(local_params, mbs)
 
     core_fn = jax.jit(core, static_argnames=("hetero",)) if jit else core

@@ -31,8 +31,10 @@ resumes from the last checkpoint (device state is gone by definition).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Iterable, Optional
+import time
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.resilience.faults import FaultInjector
@@ -103,9 +105,13 @@ class ResilientTrainer:
                  on_device_loss: Optional[Callable[[int], None]] = None,
                  log_every: int = 0):
         self.step_fn = step_fn
-        self.params = params
-        self.opt_state = opt_state
-        self.health = init_health()
+        # all training state is committed to where the params are: the
+        # step's outputs land there, and inputs placed (or left
+        # uncommitted) anywhere else would make step two compile again
+        where = getattr(jax.tree.leaves(params)[0], "sharding", None)
+        self.params = jax.device_put(params, where)
+        self.opt_state = jax.device_put(opt_state, where)
+        self.health = jax.device_put(init_health(), where)
         self.stream = stream
         self.monitor = monitor or HealthMonitor()
         self.manager = manager
@@ -117,6 +123,9 @@ class ResilientTrainer:
         self.log_every = log_every
         self.step = 0
         self.losses: Dict[int, float] = {}
+        #: wall seconds of every step call, dispatch to host sync (the
+        #: first includes compilation)
+        self.step_seconds: List[float] = []
         self.clip_scale = 1.0
         self._attempts = 0
         self._ok_streak = 0
@@ -218,11 +227,13 @@ class ResilientTrainer:
                 continue
 
             batch = self.stream.next()
+            t0 = time.perf_counter()
             self.params, self.opt_state, self.health, bundle = \
                 self.step_fn(self.params, self.opt_state, self.health,
                              batch, self._controls(
                                  self.injector.nan_at(step)))
-            b = bundle_dict(bundle)
+            b = bundle_dict(bundle)          # the step's one host sync
+            self.step_seconds.append(time.perf_counter() - t0)
             verdict = self.monitor.classify(step, b)
 
             if verdict == ABORT:
@@ -263,4 +274,5 @@ class ResilientTrainer:
             "fired_faults": [dataclasses.asdict(f)
                              for f in self.injector.fired],
             "clip_scale": self.clip_scale,
+            "step_seconds": list(self.step_seconds),
         }

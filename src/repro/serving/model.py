@@ -127,8 +127,12 @@ def paged_prefill(params, cfg: ModelConfig, cache, batch, page, slot):
     logits, k, v = prefill_forward(params, cfg, batch)
     k, v = _replicate_kv(cfg, k[:, 0], v[:, 0])     # [L, T, Hkv, hd]
     new = dict(cache)
-    new["k"] = cache["k"].at[:, page, slot].set(k.astype(cache["k"].dtype))
-    new["v"] = cache["v"].at[:, page, slot].set(v.astype(cache["v"].dtype))
+    # pool [L, P, Hkv, page_size, hd]: the (page, slot) index pair is
+    # split by the head axis, so the indexed view is [T, L, Hkv, hd]
+    new["k"] = cache["k"].at[:, page, :, slot].set(
+        jnp.swapaxes(k, 0, 1).astype(cache["k"].dtype))
+    new["v"] = cache["v"].at[:, page, :, slot].set(
+        jnp.swapaxes(v, 0, 1).astype(cache["v"].dtype))
     new["bits"] = cache["bits"].at[page, slot].set(batch["bits"][0])
     new["pos"] = cache["pos"].at[page, slot].set(batch["positions"][0])
     return logits, new
@@ -182,8 +186,8 @@ def paged_decode_step(params, cfg: ModelConfig, cache, batch, *,
         q = L.apply_rope(q, pos, cfg.rope_theta)
         k = L.apply_rope(k, pos, cfg.rope_theta)
         k, v = _replicate_kv(cfg, k, v)
-        ks = ks.at[i, page, slot].set(k[:, 0].astype(ks.dtype))
-        vs = vs.at[i, page, slot].set(v[:, 0].astype(vs.dtype))
+        ks = ks.at[i, page, :, slot].set(k[:, 0].astype(ks.dtype))
+        vs = vs.at[i, page, :, slot].set(v[:, 0].astype(vs.dtype))
         if attn == "xla":
             out = paged_decode_ref(
                 q[:, 0], ks[i], vs[i], q_bits, pos, bits_pages, pos_pages,

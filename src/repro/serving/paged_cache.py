@@ -10,7 +10,7 @@ The serving cache is a pool of fixed-size pages rather than one dense
   is computed from the same ``repro.core.bam`` machinery that drives
   the training kernels' grid compaction.
 * ``init_paged_cache`` (device) — the page pool itself:
-  ``k``/``v`` [L, P, page_size, Hkv, hd] plus device copies of the
+  ``k``/``v`` [L, P, Hkv, page_size, hd] plus device copies of the
   bits/pos slot metadata (the decode kernel evaluates the mask
   in-registers from these, exactly like the training kernels).
 
@@ -180,14 +180,15 @@ class PageTable:
 # ---------------------------------------------------------------------------
 
 def init_paged_cache(cfg, num_pages: int, page_size: int, dtype=None):
-    """Device page pool for ``cfg``: ``{"k","v"}`` [L, P, page_size,
-    Hkv, hd] (Hkv honors ``cfg.decode_kv_replicate``, like the dense
-    decode cache) plus ``{"bits","pos"}`` [P, page_size] slot metadata
-    the kernel masks from."""
+    """Device page pool for ``cfg``: ``{"k","v"}`` [L, P, Hkv, page_size,
+    hd] (Hkv honors ``cfg.decode_kv_replicate``, like the dense decode
+    cache; head-major so the decode kernel reads one (page_size, hd)
+    tile per page and KV head) plus ``{"bits","pos"}`` [P, page_size]
+    slot metadata the kernel masks from."""
     from repro.models.transformer import _cache_cfg
     ccfg = _cache_cfg(cfg)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else dtype
-    shape = (cfg.num_layers, num_pages, page_size, ccfg.num_kv_heads,
+    shape = (cfg.num_layers, num_pages, ccfg.num_kv_heads, page_size,
              ccfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             "bits": jnp.zeros((num_pages, page_size), jnp.uint32),

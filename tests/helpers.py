@@ -39,6 +39,9 @@ def run_in_subprocess(code: str, n_devices: int,
     host devices; assert success and return stdout."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    # forced host devices live on the CPU; a parent holding an
+    # accelerator must not have its children contend for it
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)
@@ -95,6 +98,7 @@ def subprocess_test(n_devices: int, timeout: int = 1200):
             env["XLA_FLAGS"] = \
                 f"--xla_force_host_platform_device_count={n_devices}"
             env[_SUBPROC_ENV] = "1"
+            env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = os.path.join(REPO, "src")
             proc = subprocess.run(
                 [sys.executable, "-m", "pytest", "-x", "-q",

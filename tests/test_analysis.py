@@ -364,6 +364,31 @@ def test_real_kernels_lint_clean():
     assert kernellint.lint_kernels() == []
 
 
+@pytest.mark.parametrize("block,ok", [
+    ((1, 128, 1, 128), False),   # token-major, one head: refused
+    ((1, 128, 4, 128), True),    # all heads: equals the array's dims
+    ((1, 100, 4, 128), True),    # second-to-last is free once -1 fits
+    ((1, 128, 4, 64), False),    # lane dim neither 128k nor the array's
+])
+def test_tpu_block_tiling_seeded(block, ok):
+    """The tiling rule flags the token-major one-head block the chip
+    refused, on the CPU and without a TPU compiler."""
+    from jax.experimental import pallas as pl
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    shape = (1, 256, 4, 128)
+    spec = pl.BlockSpec(block, lambda b, i: (b, i, 0, 0))
+    fn = pl.pallas_call(copy, grid=(1, 2), in_specs=[spec], out_specs=spec,
+                        out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+                        interpret=True)
+    jaxpr = jax.make_jaxpr(fn)(jnp.zeros(shape, jnp.float32))
+    found = kernellint.tiling_findings(jaxpr, "seeded")
+    assert (found == []) == ok, found
+    assert all(f.rule == "tpu-block-tiling" for f in found)
+
+
 def test_coverage_findings_catch_missing_tile():
     dense = np.ones((8, 8), bool)
     bm = types.SimpleNamespace(

@@ -7,6 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import bam, context_parallel as cp
 from repro.core import distribution as dist
@@ -32,7 +33,8 @@ def test_cp_single_rank_equals_sdpa(method):
     q, k, v, bits, pos, *_ = make_case()
     mask = bam.allowed_mask(bits, bits, pos, pos)[:, None]
     ref = sdpa(q, k, v, mask)
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     out = cp.cp_attention(mesh, "cp", q, k, v, bits, bits, pos, pos,
                           method=method)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
@@ -45,7 +47,8 @@ def test_cp_kernel_stats_path_equals_reference(method):
     output must still equal the dense oracle."""
     q, k, v, bits, pos, *_ = make_case()
     ref = cp.cp_reference(q, k, v, bits, bits, pos, pos)
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     out = cp.cp_attention(mesh, "cp", q, k, v, bits, bits, pos, pos,
                           method=method, impl="bam_interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -110,7 +113,8 @@ def test_plan_permutation_uncovered_seq_raises():
 
 def test_cp_attention_unknown_method_raises():
     q, k, v, bits, pos, *_ = make_case()
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="allgather.*ring"):
         cp.cp_attention(mesh, "cp", q, k, v, bits, bits, pos, pos,
                         method="butterfly")
@@ -208,7 +212,8 @@ def _gqa_case(seed=0, B=1, T=64, H=4, Hkv=2, hd=16):
 @pytest.mark.parametrize("impl", ["xla", "bam_interpret"])
 def test_cp_grads_match_reference(method, impl):
     q, k, v, bits, pos, *_ = make_case()
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     g_cp = _grads_of(
         lambda q, k, v: cp.cp_attention(mesh, "cp", q, k, v, bits, bits,
                                         pos, pos, method=method, impl=impl,
@@ -230,7 +235,8 @@ def test_cp_grads_variants(method, variant):
     kw = {"softcap": {"softcap": 30.0}, "window": {"window": 9},
           "gqa": {}}[variant]
     q, k, v, bits, pos = _gqa_case(seed=1, Hkv=Hkv)
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     g_cp = _grads_of(
         lambda q, k, v: cp.cp_attention(mesh, "cp", q, k, v, bits, bits,
                                         pos, pos, method=method,
@@ -256,7 +262,8 @@ def test_cp_grads_padding_exact_zero(method, impl):
         [("text", 0, 24), ("mod", 1, 8), ("text", 0, 16)], T)  # 16 padded
     bits = jnp.asarray(bits_np)[None]
     pos = jnp.asarray(pos_np)[None]
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     dq, dk, dv = _grads_of(
         lambda q, k, v: cp.cp_attention(mesh, "cp", q, k, v, bits, bits,
                                         pos, pos, method=method, impl=impl,
@@ -282,7 +289,8 @@ def test_cp_backward_no_quadratic_intermediate(method):
     from repro.analysis.jaxprlint import quadratic_f32 as _quadratic_f32
     T = 64
     q, k, v, bits, pos, *_ = make_case(B=1, H=2)
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
 
     def loss(impl):
         def f(q, k, v):
@@ -350,7 +358,8 @@ def test_cp_train_step_contextplan_layout():
     ctx = plan_context(bits_np, pos_np, 2, block_size=4, method="lpt")
     layout = ctx.apply(T)
     assert sorted(layout["perm"].tolist()) == list(range(T))
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
 
     params = api.init(jax.random.PRNGKey(0), cfg)
     ocfg = opt.AdamWConfig(lr=1e-2, warmup_steps=0, schedule="constant")
@@ -399,7 +408,8 @@ def test_cp_train_step_missing_bits_raises():
     T, B = 32, 1
     bits_np, pos_np = bam.build_sample_bits([("text", 0, T)], T)
     layout = plan_context(bits_np, pos_np, 1, block_size=4).apply(T)
-    mesh = jax.make_mesh((1,), ("cp",))
+    mesh = jax.make_mesh((1,), ("cp",),
+                         axis_types=(AxisType.Auto,))
     step = steps.make_cp_train_step(cfg, layout, mesh)
     params = {}
     batch = {"tokens": jnp.zeros((B, T), jnp.int32),

@@ -9,7 +9,9 @@ def test_pipeline_forward_and_grads_4_stages():
     code = """
 import jax, jax.numpy as jnp
 from repro.core import modality_parallel as mp
-mesh = jax.make_mesh((4,), ("stage",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 d = 32
 per_stage = [{"w": jax.random.normal(jax.random.fold_in(key, s),
@@ -43,7 +45,9 @@ from repro.configs.base import get_config
 from repro.models import transformer as T
 from repro.models import layers as L
 cfg = get_config("qwen3-1.7b", reduced=True).replace(num_layers=4)
-mesh = jax.make_mesh((4,), ("stage",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 full = T.init(key, cfg)
 per_stage = [jax.tree.map(lambda a: a[s], full["layers"]) for s in range(4)]
@@ -122,7 +126,9 @@ batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)),
          "positions": jnp.broadcast_to(
              jnp.arange(T, dtype=jnp.int32)[None], (B, T))}
 l_plain, _ = moe.forward(params, cfg, batch)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 shd.set_rules(shd.Rules(seq_parallel=False))
 shd.set_mesh(mesh)
 try:

@@ -46,9 +46,9 @@ def _paged_fixture(page_size, Hkv, hd, layouts, seed=0):
         table.alloc(rid, n)
         table.write(rid, np.arange(n), bits, pos)
     P = table.num_pages
-    k_pages = jnp.asarray(rng.normal(size=(P, page_size, Hkv, hd)),
+    k_pages = jnp.asarray(rng.normal(size=(P, Hkv, page_size, hd)),
                           jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(P, page_size, Hkv, hd)),
+    v_pages = jnp.asarray(rng.normal(size=(P, Hkv, page_size, hd)),
                           jnp.float32)
     return table, k_pages, v_pages, list(range(len(layouts)))
 
@@ -319,7 +319,7 @@ def test_paged_cache_guards():
     assert table.num_free == 3
     cfg = tiny_cfg()
     cache = init_paged_cache(cfg, 4, 4)
-    assert cache["k"].shape == (2, 4, 4, 2, 8)
+    assert cache["k"].shape == (2, 4, 2, 4, 8)
     assert int(cache["bits"].sum()) == 0
 
 

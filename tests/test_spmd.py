@@ -10,6 +10,7 @@ Multi-device tests re-exec themselves in a subprocess with a forced
 host device count (tests/helpers.subprocess_test); under the
 multi-device CI job (global XLA_FLAGS) they run in-process."""
 import pathlib
+import types
 
 import numpy as np
 import pytest
@@ -316,6 +317,10 @@ def test_default_mesh_raises_with_xla_flags_hint():
     with pytest.raises(ValueError,
                        match="xla_force_host_platform_device_count"):
         default_mesh(1024)
+    # forced host devices are no remedy on an accelerator: no such hint
+    tpu = types.SimpleNamespace(platform="tpu")
+    with pytest.raises(ValueError, match="has 1; run on a larger mesh$"):
+        default_mesh(4, devices=[tpu])
 
 
 def test_runner_rejects_wrong_mesh_axis_size():

@@ -7,6 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs.base import SHAPES, get_config
 from repro.data.synthetic import MultimodalDataset
@@ -71,7 +72,8 @@ def test_dryrun_machinery_host_scale():
     from repro.launch import specs as S
 
     cfg = get_config("qwen3-1.7b", reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = shd.Rules(seq_parallel=False)
     shd.set_rules(rules)
     shd.set_mesh(mesh)
@@ -158,3 +160,49 @@ def test_dryrun_preserves_user_xla_flags():
     flags, devices = run(None)
     assert flags == "--xla_force_host_platform_device_count=512"
     assert devices == 512
+
+
+def test_compilation_cache_placement(tmp_path):
+    """``launch.train.enable_compilation_cache``: compiled entries land
+    where ``JAX_COMPILATION_CACHE_DIR`` says when it is set; otherwise
+    the cache is the fixed ``<repo>/.jax_cache``. Importing the module
+    sets nothing."""
+    import os
+    import subprocess
+    import sys
+
+    from .helpers import REPO
+
+    code = ("import sys, jax, jax.numpy as jnp\n"
+            "from repro.launch import train\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "print(train.enable_compilation_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "if sys.argv[1] == 'compile':\n"
+            "    jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8))"
+            ".block_until_ready()\n")
+
+    def run(cache_dir):
+        # only the run with its own cache directory compiles: the other
+        # would write into the checkout
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   PYTHONPATH=os.path.join(REPO, "src"))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if cache_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        proc = subprocess.run(
+            [sys.executable, "-c", code,
+             "compile" if cache_dir else "no-compile"],
+            env=env, capture_output=True, text=True, timeout=600,
+            cwd=tmp_path)
+        assert proc.returncode == 0, proc.stderr
+        return proc.stdout.split()
+
+    where = str(tmp_path / "cache")
+    assert run(where) == [where] * 3
+    assert os.listdir(where)                 # the jit's entry landed
+    default = os.path.join(REPO, ".jax_cache")
+    before, returned, after = run(None)
+    assert (returned, after) == (default, default)
+    assert before in ("None", "")            # the import set nothing
