@@ -1,0 +1,11 @@
+"""trainer.dispatch_idle_ms: device idle time while the trainer's
+innermost span is ``trainer.dispatch`` (the step's controls placed and
+its work enqueued) per step in the traced window (``scopes.py``)."""
+
+
+def read(record):
+    prog = (record.get("trace") or {}).get("program")
+    if not prog or not record.get("steps"):
+        return None
+    return 1e3 * prog["idle_by_span_s"].get("trainer.dispatch", 0.0) \
+        / record["steps"]

@@ -89,15 +89,17 @@ class ModalityModule:
         mod_p = params["module"]
         if self.frozen_module:
             mod_p = jax.tree.map(jax.lax.stop_gradient, mod_p)
-        out = M.encoder_forward(mod_p, self.cfg, embeds)
+        with jax.named_scope("encoder"):
+            out = M.encoder_forward(mod_p, self.cfg, embeds)
         if self.postprocess_module_callback:
             out = self.postprocess_module_callback(inputs, out)
         proj_p = params["projector"]
         if self.frozen_projector:
             proj_p = jax.tree.map(jax.lax.stop_gradient, proj_p)
-        out = out @ proj_p["w1"]
-        if "w2" in proj_p:
-            out = jax.nn.gelu(out) @ proj_p["w2"]
+        with jax.named_scope("projector"):
+            out = out @ proj_p["w1"]
+            if "w2" in proj_p:
+                out = jax.nn.gelu(out) @ proj_p["w2"]
         if self.postprocess_projector_callback:
             out = self.postprocess_projector_callback(inputs, out)
         return out
@@ -203,6 +205,7 @@ class MultimodalModule:
     def merged_length(self, text_len: int) -> int:
         return text_len + sum(e.num_tokens for e in self.encoders.values())
 
+    @jax.named_scope("merge")
     def build_merge(self, text_tokens, enc_outputs: Dict[str, Any],
                     layout: Optional[List[Tuple]] = None):
         """Merge text tokens + projected encoder outputs into one

@@ -254,40 +254,45 @@ def run_attention(p: Params, cfg: ModelConfig, x_q, *, x_kv=None, q_pos=None,
         # token axis shard_map'd over cfg.cp_axis; differentiable on
         # every impl (combining-aware custom_vjp in the CP bodies).
         from repro.core.context_parallel import cp_attention
-        out = cp_attention(
-            cfg.cp_mesh, cfg.cp_axis, q, k, v, bits,
-            bits if kv_bits is None else kv_bits, q_pos,
-            q_pos if kv_pos is None else kv_pos, method=cfg.cp_method,
-            softcap=cfg.attn_softcap, window=window, impl=cfg.attn_impl)
+        with jax.named_scope("sdpa"):
+            out = cp_attention(
+                cfg.cp_mesh, cfg.cp_axis, q, k, v, bits,
+                bits if kv_bits is None else kv_bits, q_pos,
+                q_pos if kv_pos is None else kv_pos, method=cfg.cp_method,
+                softcap=cfg.attn_softcap, window=window, impl=cfg.attn_impl)
         return out.reshape(b, tq, cfg.q_dim) @ p["wo"], (k, v)
     elif cfg.attn_impl != "xla" and bits is not None:
         # fused Pallas BAM path: GQA folded into the kernel's index
         # maps, bitfield mask evaluated in-registers, custom_vjp with
         # (out, lse) residuals — the training hot path.
         from repro.kernels.ops import auto_block, bam_attention
-        out = bam_attention(
-            q, k, v, bits, bits if kv_bits is None else kv_bits,
-            q_pos, q_pos if kv_pos is None else kv_pos,
-            softcap=cfg.attn_softcap, window=window, impl=cfg.attn_impl,
-            block_q=auto_block(tq), block_k=auto_block(k.shape[1]))
+        with jax.named_scope("sdpa"):
+            out = bam_attention(
+                q, k, v, bits, bits if kv_bits is None else kv_bits,
+                q_pos, q_pos if kv_pos is None else kv_pos,
+                softcap=cfg.attn_softcap, window=window,
+                impl=cfg.attn_impl, block_q=auto_block(tq),
+                block_k=auto_block(k.shape[1]))
         return out.reshape(b, tq, cfg.q_dim) @ p["wo"], (k, v)
-    # n_rep from the actual tensor: decode caches may carry replicated
-    # KV heads (cfg.decode_kv_replicate)
-    n_rep = cfg.num_heads // k.shape[2]
-    kf, vf = repeat_kv(k, n_rep), repeat_kv(v, n_rep)
-    chunk = cfg.attn_q_chunk
-    if mask_fn is not None and chunk and tq % chunk == 0 and tq > chunk:
-        out = sdpa_q_chunked(q, kf, vf, mask_fn, chunk,
-                             softcap=cfg.attn_softcap)
-    else:
-        if mask is None and mask_fn is not None:
-            mask = mask_fn(0, tq)
-        if mask is None:
-            assert q_pos is not None
-            mask = causal_mask(q_pos,
-                               kv_pos if kv_pos is not None else q_pos,
-                               window)
-        out = sdpa(q, kf, vf, mask, softcap=cfg.attn_softcap)
+    with jax.named_scope("sdpa"):
+        # n_rep from the actual tensor: decode caches may carry
+        # replicated KV heads (cfg.decode_kv_replicate)
+        n_rep = cfg.num_heads // k.shape[2]
+        kf, vf = repeat_kv(k, n_rep), repeat_kv(v, n_rep)
+        chunk = cfg.attn_q_chunk
+        if mask_fn is not None and chunk and tq % chunk == 0 \
+                and tq > chunk:
+            out = sdpa_q_chunked(q, kf, vf, mask_fn, chunk,
+                                 softcap=cfg.attn_softcap)
+        else:
+            if mask is None and mask_fn is not None:
+                mask = mask_fn(0, tq)
+            if mask is None:
+                assert q_pos is not None
+                mask = causal_mask(q_pos,
+                                   kv_pos if kv_pos is not None else q_pos,
+                                   window)
+            out = sdpa(q, kf, vf, mask, softcap=cfg.attn_softcap)
     out = out.reshape(b, tq, cfg.q_dim) @ p["wo"]
     return out, (k, v)
 

@@ -55,11 +55,13 @@ def encoder_forward(params, cfg: ModelConfig, embeds):
     def body(x, lp):
         def blk(x):
             h = L.apply_norm(cfg, lp["ln1"], x)
-            a, _ = L.run_attention(lp["attn"], cfg, h, q_pos=pos, mask=full,
-                                   rope=False)
+            with jax.named_scope("attention"):
+                a, _ = L.run_attention(lp["attn"], cfg, h, q_pos=pos,
+                                       mask=full, rope=False)
             x = x + a
             h = L.apply_norm(cfg, lp["ln2"], x)
-            return x + L.run_mlp(lp["mlp"], h, "gelu")
+            with jax.named_scope("mlp"):
+                return x + L.run_mlp(lp["mlp"], h, "gelu")
         if cfg.remat:
             blk = jax.checkpoint(blk)
         return blk(x), None

@@ -135,18 +135,20 @@ def _block(cfg: ModelConfig, p, x, batch, layer_idx, ffn: Optional[FFN]):
         kernel_bits = batch["bits"]
 
     h = L.apply_norm(cfg, p["ln1"], x)
-    attn_out, kv = L.run_attention(
-        p["attn"], cfg, h, q_pos=batch["positions"], mask_fn=mask_fn,
-        pos3=batch.get("pos3"), bits=kernel_bits,
-        window=cfg.sliding_window if kernel_bits is not None else 0)
+    with jax.named_scope("attention"):
+        attn_out, kv = L.run_attention(
+            p["attn"], cfg, h, q_pos=batch["positions"], mask_fn=mask_fn,
+            pos3=batch.get("pos3"), bits=kernel_bits,
+            window=cfg.sliding_window if kernel_bits is not None else 0)
     if cfg.post_block_norm:
         attn_out = L.apply_norm(cfg, p["post_ln1"], attn_out)
     x = x + attn_out
     h = L.apply_norm(cfg, p["ln2"], x)
-    if ffn is None:
-        mlp_out, aux = _default_ffn(p, h, cfg)
-    else:
-        mlp_out, aux = ffn(p, h, layer_idx)
+    with jax.named_scope("mlp"):
+        if ffn is None:
+            mlp_out, aux = _default_ffn(p, h, cfg)
+        else:
+            mlp_out, aux = ffn(p, h, layer_idx)
     if cfg.post_block_norm:
         mlp_out = L.apply_norm(cfg, p["post_ln2"], mlp_out)
     x = x + mlp_out
@@ -173,6 +175,7 @@ def embed_tokens(params, cfg: ModelConfig, batch):
     return x
 
 
+@jax.named_scope("llm")
 def hidden(params, cfg: ModelConfig, batch, ffn: Optional[FFN] = None):
     x = embed_tokens(params, cfg, batch)
 
@@ -193,6 +196,7 @@ def hidden(params, cfg: ModelConfig, batch, ffn: Optional[FFN] = None):
     return L.apply_norm(cfg, params["final_ln"], x), {"aux_loss": aux}
 
 
+@jax.named_scope("lm_head")
 def unembed(params, cfg: ModelConfig, h):
     w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = h @ w

@@ -63,6 +63,7 @@ def init(cfg: AdamWConfig, params, frozen_mask=None):
     }
 
 
+@jax.named_scope("optimizer")
 def update(cfg: AdamWConfig, grads, state, params, frozen_mask=None):
     """Returns (new_params, new_state, metrics)."""
     if frozen_mask is None:

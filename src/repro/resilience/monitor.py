@@ -93,53 +93,55 @@ def make_resilient_train_step(loss_fn, ocfg: opt.AdamWConfig,
 
     def step(params, opt_state, health, batch, controls):
         (loss, _aux), grads = value_and_grad_fn(params, batch)
-        # deterministic fault injection: a traced switch multiplies
-        # every grad by NaN — exactly what a real overflow looks like
-        # downstream, with none of the nondeterminism
-        poison = jnp.where(controls["inject_nan"] > 0,
-                           jnp.float32(np.nan), jnp.float32(1.0))
-        grads = jax.tree.map(lambda g: g * poison.astype(g.dtype), grads)
-        gnorm = opt.global_norm(grads)
-        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-        ok = finite & (gnorm <= controls["max_grad_norm"])
+        with jax.named_scope("health"):
+            # deterministic fault injection: a traced switch multiplies
+            # every grad by NaN — exactly what a real overflow looks like
+            # downstream, with none of the nondeterminism
+            poison = jnp.where(controls["inject_nan"] > 0,
+                               jnp.float32(np.nan), jnp.float32(1.0))
+            grads = jax.tree.map(lambda g: g * poison.astype(g.dtype), grads)
+            gnorm = opt.global_norm(grads)
+            finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+            ok = finite & (gnorm <= controls["max_grad_norm"])
 
-        # EMA loss-spike score (computed BEFORE this step's loss is
-        # folded in — a spike must not dilute its own baseline)
-        warm = health["count"] > 0
-        mean = jnp.where(warm, health["ema"], loss)
-        dev = loss - mean
-        spike = jnp.where(
-            warm & finite,
-            jnp.abs(dev) * jax.lax.rsqrt(health["var"] + 1e-8),
-            jnp.float32(0.0))
+            # EMA loss-spike score (computed BEFORE this step's loss is
+            # folded in — a spike must not dilute its own baseline)
+            warm = health["count"] > 0
+            mean = jnp.where(warm, health["ema"], loss)
+            dev = loss - mean
+            spike = jnp.where(
+                warm & finite,
+                jnp.abs(dev) * jax.lax.rsqrt(health["var"] + 1e-8),
+                jnp.float32(0.0))
 
-        # the optimizer must never see non-finite grads (NaN would
-        # poison the Adam moments even if params were later restored):
-        # zero them, run the update, then select old vs new on `ok`
-        safe_scale = jnp.where(ok, controls["clip_scale"],
-                               jnp.float32(0.0))
-        safe = jax.tree.map(
-            lambda g: (g.astype(jnp.float32) * safe_scale).astype(g.dtype),
-            grads)
+            # the optimizer must never see non-finite grads (NaN would
+            # poison the Adam moments even if params were later restored):
+            # zero them, run the update, then select old vs new on `ok`
+            safe_scale = jnp.where(ok, controls["clip_scale"],
+                                   jnp.float32(0.0))
+            safe = jax.tree.map(
+                lambda g: (g.astype(jnp.float32) * safe_scale).astype(g.dtype),
+                grads)
         new_p, new_s, _om = opt.update(ocfg, safe, opt_state, params,
                                        frozen_mask)
-        sel = lambda a, b: jnp.where(ok, a, b)           # noqa: E731
-        new_p = jax.tree.map(sel, new_p, params)
-        new_s = jax.tree.map(sel, new_s, opt_state)
+        with jax.named_scope("health"):
+            sel = lambda a, b: jnp.where(ok, a, b)           # noqa: E731
+            new_p = jax.tree.map(sel, new_p, params)
+            new_s = jax.tree.map(sel, new_s, opt_state)
 
-        upd = ok  # EMA tracks only applied steps: a skipped spike must
-        #           not drag the baseline toward itself
-        new_health = {
-            "ema": jnp.where(upd, ema_decay * mean
-                             + (1 - ema_decay) * loss, health["ema"]),
-            "var": jnp.where(upd, ema_decay * health["var"]
-                             + (1 - ema_decay) * dev * dev,
-                             health["var"]),
-            "count": health["count"] + upd.astype(jnp.int32),
-        }
-        bundle = jnp.stack([
-            loss.astype(jnp.float32), gnorm.astype(jnp.float32), spike,
-            1.0 - finite.astype(jnp.float32), ok.astype(jnp.float32)])
+            upd = ok  # EMA tracks only applied steps: a skipped spike must
+            #           not drag the baseline toward itself
+            new_health = {
+                "ema": jnp.where(upd, ema_decay * mean
+                                 + (1 - ema_decay) * loss, health["ema"]),
+                "var": jnp.where(upd, ema_decay * health["var"]
+                                 + (1 - ema_decay) * dev * dev,
+                                 health["var"]),
+                "count": health["count"] + upd.astype(jnp.int32),
+            }
+            bundle = jnp.stack([
+                loss.astype(jnp.float32), gnorm.astype(jnp.float32), spike,
+                1.0 - finite.astype(jnp.float32), ok.astype(jnp.float32)])
         return new_p, new_s, new_health, bundle
 
     return step

@@ -27,6 +27,17 @@ the loss trajectory matches an uninterrupted run). A ``device_loss``
 triggers the ``on_device_loss`` hook — ``launch/train`` re-runs
 ``parallelize()`` over the shrunken ``ClusterSpec`` there — then
 resumes from the last checkpoint (device state is gone by definition).
+
+The loop writes host spans into the profiler's trace
+(``jax.profiler.TraceAnnotation``; about a microsecond each when no trace
+is being taken): ``trainer.step`` around each pass of the loop (a
+``StepTraceAnnotation``, so profilers show a step view), and inside it
+``trainer.batch`` (the stream's ``next``), ``trainer.dispatch`` (the
+step's controls and its call, which enqueues the work),
+``trainer.health_read`` (the bundle's host read, where the host waits for
+the device) and ``trainer.checkpoint`` / ``trainer.restore``. Each span's
+count is the count of what it wraps: batches drawn, dispatches, host
+syncs, saves and restores.
 """
 from __future__ import annotations
 
@@ -146,13 +157,14 @@ class ResilientTrainer:
     def save_checkpoint(self, on_entry=None) -> Optional[str]:
         if self.manager is None:
             return None
-        meta = {**self.meta, "step": self.step,
-                "cursor": self.stream.cursor,
-                "clip_scale": self.clip_scale}
-        path = self.manager.save(self.step, self._state_tree(),
-                                 meta=meta, on_entry=on_entry)
-        self.monitor.log.emit("checkpoint", self.step, dir=path,
-                              cursor=self.stream.cursor)
+        with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+            meta = {**self.meta, "step": self.step,
+                    "cursor": self.stream.cursor,
+                    "clip_scale": self.clip_scale}
+            path = self.manager.save(self.step, self._state_tree(),
+                                     meta=meta, on_entry=on_entry)
+            self.monitor.log.emit("checkpoint", self.step, dir=path,
+                                  cursor=self.stream.cursor)
         return path
 
     def adopt_state(self, params, opt_state, health=None, *,
@@ -173,13 +185,14 @@ class ResilientTrainer:
                               cursor=self.stream.cursor)
 
     def _restore(self, why: str) -> None:
-        tree, step, meta = self.manager.restore(self._state_tree())
-        self.params, self.opt_state = tree["params"], tree["opt"]
-        self.health = tree["health"]
-        self.step = int(meta.get("step", step))
-        self.stream.seek(int(meta.get("cursor", self.step)))
-        self.monitor.log.emit("restore", self.step, why=why,
-                              cursor=self.stream.cursor)
+        with jax.profiler.TraceAnnotation("trainer.restore"):
+            tree, step, meta = self.manager.restore(self._state_tree())
+            self.params, self.opt_state = tree["params"], tree["opt"]
+            self.health = tree["health"]
+            self.step = int(meta.get("step", step))
+            self.stream.seek(int(meta.get("cursor", self.step)))
+            self.monitor.log.emit("restore", self.step, why=why,
+                                  cursor=self.stream.cursor)
 
     # -- the loop ----------------------------------------------------------
 
@@ -213,54 +226,59 @@ class ResilientTrainer:
         """Train until ``self.step == num_steps``; returns a summary
         (losses by step, verdict counters, fired faults)."""
         while self.step < num_steps:
-            step = self.step
-            self.injector.check_crash(step)
-            loss_ev = self.injector.check_device_loss(step)
-            if loss_ev is not None:
-                self.monitor.log.emit("device-loss", step,
-                                      lost=loss_ev.lost)
-                if self.on_device_loss is not None:
-                    self.on_device_loss(loss_ev.lost)
-                if self.manager is not None and \
-                        self.manager.latest() is not None:
-                    self._restore("device-loss")
-                continue
+            with jax.profiler.StepTraceAnnotation(
+                    "trainer.step", step_num=self.step):
+                step = self.step
+                self.injector.check_crash(step)
+                loss_ev = self.injector.check_device_loss(step)
+                if loss_ev is not None:
+                    self.monitor.log.emit("device-loss", step,
+                                          lost=loss_ev.lost)
+                    if self.on_device_loss is not None:
+                        self.on_device_loss(loss_ev.lost)
+                    if self.manager is not None and \
+                            self.manager.latest() is not None:
+                        self._restore("device-loss")
+                    continue
 
-            batch = self.stream.next()
-            t0 = time.perf_counter()
-            self.params, self.opt_state, self.health, bundle = \
-                self.step_fn(self.params, self.opt_state, self.health,
-                             batch, self._controls(
-                                 self.injector.nan_at(step)))
-            b = bundle_dict(bundle)          # the step's one host sync
-            self.step_seconds.append(time.perf_counter() - t0)
-            verdict = self.monitor.classify(step, b)
+                with jax.profiler.TraceAnnotation("trainer.batch"):
+                    batch = self.stream.next()
+                t0 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("trainer.dispatch"):
+                    self.params, self.opt_state, self.health, bundle = \
+                        self.step_fn(self.params, self.opt_state,
+                                     self.health, batch, self._controls(
+                                         self.injector.nan_at(step)))
+                with jax.profiler.TraceAnnotation("trainer.health_read"):
+                    b = bundle_dict(bundle)      # the step's one host sync
+                self.step_seconds.append(time.perf_counter() - t0)
+                verdict = self.monitor.classify(step, b)
 
-            if verdict == ABORT:
-                raise TrainingAborted(
-                    f"monitor aborted training at step {step}: {b}")
-            if verdict == ROLLBACK:
-                self._rollback(step, "verdict")
-                continue
-            # ok | skip: the in-jit gate already did the right thing
-            self.step += 1
-            if verdict == OK:
-                self.losses[step] = b["loss"]
-                self._ok_streak += 1
-                if self._ok_streak >= self.policy.recover_steps and \
-                        self.clip_scale != 1.0:
-                    self.clip_scale = 1.0
-                    self._attempts = 0
-                    self.monitor.log.emit("recovered", step)
-                if self.log_every and step % self.log_every == 0:
-                    print(f"step {step:5d} loss {b['loss']:.4f} "
-                          f"gnorm {b['grad_norm']:.3f}", flush=True)
-            if self.ckpt_every and verdict == OK and \
-                    self.step % self.ckpt_every == 0:
-                # a crash_in_save fault at this step kills the write
-                # mid-shard; CrashInjected propagates like a SIGKILL
-                self.save_checkpoint(
-                    on_entry=self.injector.save_hook(step))
+                if verdict == ABORT:
+                    raise TrainingAborted(
+                        f"monitor aborted training at step {step}: {b}")
+                if verdict == ROLLBACK:
+                    self._rollback(step, "verdict")
+                    continue
+                # ok | skip: the in-jit gate already did the right thing
+                self.step += 1
+                if verdict == OK:
+                    self.losses[step] = b["loss"]
+                    self._ok_streak += 1
+                    if self._ok_streak >= self.policy.recover_steps and \
+                            self.clip_scale != 1.0:
+                        self.clip_scale = 1.0
+                        self._attempts = 0
+                        self.monitor.log.emit("recovered", step)
+                    if self.log_every and step % self.log_every == 0:
+                        print(f"step {step:5d} loss {b['loss']:.4f} "
+                              f"gnorm {b['grad_norm']:.3f}", flush=True)
+                if self.ckpt_every and verdict == OK and \
+                        self.step % self.ckpt_every == 0:
+                    # a crash_in_save fault at this step kills the write
+                    # mid-shard; CrashInjected propagates like a SIGKILL
+                    self.save_checkpoint(
+                        on_entry=self.injector.save_hook(step))
         return self.summary()
 
     def summary(self) -> Dict[str, Any]:

@@ -301,7 +301,8 @@ def make_mllm_train_step(mllm, ocfg: Optional[opt.AdamWConfig] = None):
         gathered = jnp.take_along_axis(
             lab_src, jnp.clip(txt_idx, 0, lab_src.shape[1] - 1), axis=1)
         labels = jnp.where(is_text, gathered, 0)
-        loss = cross_entropy(logits, labels, valid=is_text)
+        with jax.named_scope("lm_head"):
+            loss = cross_entropy(logits, labels, valid=is_text)
         return loss + aux.get("aux_loss", 0.0), {"ce": loss}
 
     def step(params, opt_state, batch):
