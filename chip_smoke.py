@@ -18,11 +18,13 @@ projector), on 1024 text tokens plus 576 vision tokens (1600 merged):
 
 Four chips (``--chips 4``), and nothing else:
 
-  (i)  ``launch/train --mllm vlm --spmd`` on a 4-device plan against a
-       replay-mode run over the same steps;
+  (i)  ``launch/train --mllm vlm --spmd`` on a 4-device plan, its LLM
+       stages on the default attention (the fused BAM kernels on a TPU),
+       against a replay-mode run over the same steps on XLA attention;
   (ii) ``make_cp_train_step`` on a 4-way ``cp`` mesh at LLM-S widths
        (depth cut to 2 layers) with ep/ee/mp masks, allgather and ring,
-       against ``make_train_step`` on one device.
+       on the fused kernel chunks, against ``make_train_step`` on one
+       device on XLA attention.
 
 Every phase runs in this one process: a chip belongs to one process at a
 time. The script refuses to run where JAX finds no TPU, and outside a
@@ -195,7 +197,9 @@ def four_device_plan(args, path: str):
 
 def spmd_vs_replay(*, reduced: bool = False):
     """(i): the same VLM, steps and batches through the SPMD pipeline on
-    a four-device plan and through the single-device replay trainer."""
+    a four-device plan, with the LLM's attention as the platform picks it
+    (``attn_impl="auto"``: the fused kernels on a TPU), and through the
+    single-device replay trainer on XLA attention."""
     import tempfile
     with tempfile.TemporaryDirectory() as tmp:
         path = os.path.join(tmp, "plan.json")
@@ -206,7 +210,7 @@ def spmd_vs_replay(*, reduced: bool = False):
               flush=True)
         replay = run_phase("replay", args, "xla")
         spmd = run_phase("spmd", vlm_args(reduced=reduced,
-                                          extra=extra + ("--spmd",)), "xla")
+                                          extra=extra + ("--spmd",)), "auto")
     return compare("(i) spmd vs replay", replay, spmd), [replay, spmd]
 
 
@@ -255,8 +259,8 @@ def cp_vs_single(*, reduced: bool = False, kernel_impl: str = "bam_kernel",
                               method="lpt").apply(T)
         # keep only each step's metrics: at LLM-S widths the updated
         # params and optimizer state are ~6 GB a copy on a 16 GB chip
-        ref = jax.jit(steps.make_train_step(cfg, ocfg))(
-            params, state, batch)[2]
+        ref = jax.jit(steps.make_train_step(cfg.replace(attn_impl="xla"),
+                                            ocfg))(params, state, batch)[2]
         want = (float(ref["loss"]), float(ref["grad_norm"]))
         for method in ("allgather", "ring"):
             step = jax.jit(steps.make_cp_train_step(

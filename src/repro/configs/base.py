@@ -130,7 +130,10 @@ class ModelConfig:
     remat: bool = True
     seq_shard_activations: bool = True  # Megatron sequence parallelism
     loss_chunk: int = 1024        # chunked cross-entropy over seq (0 = off)
-    attn_impl: str = "xla"        # xla | bam_kernel | bam_interpret
+    # auto: the fused BAM kernel on TPU for calls with BAM bits, else
+    # XLA (models.layers.resolve_attn_impl) | xla | bam_kernel |
+    # bam_interpret
+    attn_impl: str = "auto"
     # decode: replicate GQA KV heads in the cache up to this count so the
     # cache head dim divides the model axis (head-sharded attention, no
     # cross-shard softmax). 0 = off. Memory/collective trade, §Perf.

@@ -113,6 +113,24 @@ def allowed_mask(q_bits, kv_bits, q_pos, kv_pos, window: int = 0):
     return nonpad & same_doc & bit_ok & rule
 
 
+TILE_EMPTY, TILE_PARTIAL, TILE_FULL = 0, 1, 2
+
+
+def tile_classes(q_bits, kv_bits, q_pos, kv_pos, block_q: int, block_k: int,
+                 window: int = 0):
+    """Classify each [block_q, block_k] tile of the mask on the device:
+    TILE_EMPTY (no pair allowed), TILE_PARTIAL or TILE_FULL (every pair
+    allowed). [B, Tq] / [B, Tk] inputs whose lengths are block multiples
+    -> int32 [B, Tq // block_q, Tk // block_k]. Reduced from
+    ``allowed_mask`` itself, so exact for every bitfield; XLA fuses the
+    boolean mask into the two reductions."""
+    m = allowed_mask(q_bits, kv_bits, q_pos, kv_pos, window)
+    B, Tq, Tk = m.shape
+    m = m.reshape(B, Tq // block_q, block_q, Tk // block_k, block_k)
+    return (jnp.any(m, axis=(2, 4)).astype(jnp.int32)
+            + jnp.all(m, axis=(2, 4)).astype(jnp.int32))
+
+
 def causal_bits(batch: int, seq: int, dtype=jnp.uint32):
     """Degenerate BAM for a pure-text causal LM (paper §4.3.1: causal is
     the 1-D special case)."""

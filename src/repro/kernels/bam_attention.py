@@ -4,30 +4,50 @@ The paper represents multimodal attention masks as 1-D per-token integer
 bitfields (BAM) and materializes [T,T] masks only transiently inside the
 attention op (their FlexAttention path). The TPU-native analogue built
 here goes further: the mask is evaluated **in-registers inside the
-kernel** from the two bitfield vectors — the [T,T] mask never exists in
-HBM *or* VMEM, only a [bq,bk] tile of it lives in VREGs per grid step.
+kernel** from per-token vectors — the [T,T] mask never exists in HBM
+*or* VMEM, only a [bq,bk] tile of it lives in VREGs per grid step.
+
+Mask words: the wrappers rewrite each token's bitfield and position
+into three or four int32 words (``_mask_words``) from which a tile's
+mask takes seven elementwise operations; the rewrite is exact for every
+bitfield (tested against ``repro.core.bam.allowed_mask``).
 
 Layout / tiling (dense grid):
   grid = (B, H, Tq/bq, Tk/bk), dimension_semantics = (parallel, parallel,
   parallel, arbitrary). Online-softmax running stats (m, l) and the
   output accumulator live in VMEM scratch and persist across the
   arbitrary (k-block) grid dimension; the output tile is written at the
-  last k step. bq = bk = 128 matches the MXU systolic tile.
+  last k step. The wrappers take any (8, 128)-aligned tiles;
+  ``ops.flash_blocks`` picks them from the shape for the training path.
 
   Mosaic requires the last two dimensions of every block to be
   multiples of (8, 128) or equal to the array's. The wrappers keep the
   public token-major [B, T, H, hd] signature and hand the kernels:
     * head-major tensors [B, H, T, hd] — (bq, hd) tiles;
-    * q-side bitfields/positions as columns [B, Tq, 1] — (bq, 1) tiles;
-    * k-side bitfields/positions as rows [B, 1, Tk] — (1, bk) tiles;
-    * per-row statistics (lse, m, l, delta) as columns [B, H, Tq, 1],
-      and 2-D (bq, 1) running-stat scratch.
+    * in the forward and dQ kernels, query-side words as columns
+      [B, Tq, 1] — (bq, 1) tiles — and key-side words as rows [B, 1, Tk]
+      — (1, bk) tiles; per-row statistics (lse, delta) as columns
+      [B, H, Tq, 1], and 2-D (bq, 1) running-stat scratch;
+    * in the dK/dV kernel, which works on transposed [bk, bq] tiles
+      (Sᵀ = K Qᵀ, so that no matmul takes a transposed operand),
+      key-side words as columns, query-side words and statistics as
+      rows [B, 1, Tq] / [B, H, 1, Tq].
+
+Precision: q, k, v, dO — and the probabilities P and dS where they are
+matmul operands — enter the MXU in the inputs' dtype (bf16 in
+training) with f32 accumulation; the scale, the mask, the exponentials,
+the online-softmax statistics (m, l, lse, delta) and the VMEM
+accumulators are f32. Float32 inputs keep float32 operands throughout.
 
 Block sparsity, two levels (beyond-paper):
-  * in-kernel skip (``block_skip``): the kernel reduces the [bq,bk]
-    bitfield intersection before touching the MXU; a fully-masked tile
-    skips the QK^T matmul via ``pl.when`` — but still pays its grid step
-    and K/V copies.
+  * tile classes (dense grid, ``block_skip``): the wrapper classifies
+    every tile on the device from the bitfields
+    (``repro.core.bam.tile_classes``: empty, partial or full, exact for
+    every bitfield) and hands the classes in by scalar prefetch. An
+    empty tile skips the MXU, a full one skips the per-element mask,
+    and only a partial tile evaluates the mask in-registers. An empty
+    tile's inner operands point at the nearest tile of its row that
+    computes, so it costs its grid step but no copy.
   * grid compaction (``block_map``): a host-side
     ``repro.core.bam.build_block_map`` precomputes the active
     (q-block, k-block) tile list from the block-level bitfield
@@ -37,7 +57,9 @@ Block sparsity, two levels (beyond-paper):
     neither a grid step nor a K/V DMA.
 
 GQA: the K/V BlockSpec index_map folds the q-head -> kv-head mapping
-(h // n_rep), so no jnp.repeat of K/V ever materializes.
+(h // n_rep), so no jnp.repeat of K/V ever materializes; the dense
+dK/dV grid runs over KV heads and accumulates the n_rep query heads of
+each in VMEM.
 
 Forward modes (``return_mode``):
   * ``"out"``       — normalized attention output only;
@@ -49,17 +71,19 @@ Forward modes (``return_mode``):
     what the context-parallel ring/allgather bodies consume.
 
 Backward: ``bam_flash_attention_bwd`` is a pair of fused kernels — dQ
-over a (B, H, nq, nk) grid and dK/dV over the transposed (B, H, nk, nq)
-grid — that recompute the logits tile-by-tile from (q, k, lse), apply
-the bitfield mask in-registers, and accumulate gradients in VMEM
-scratch. Both honor ``block_skip`` and ``block_map`` exactly like the
-forward. The old recompute-through-XLA path survives only as the
+over a (B, H, nq, nk) grid and dK/dV over the transposed
+(B, Hkv, nk, n_rep * nq) grid — that recompute the logits tile-by-tile
+from (q, k, lse), apply the mask in-registers, and accumulate gradients
+in VMEM scratch. Both honor ``block_skip`` and ``block_map`` exactly
+like the forward.
+
+Device traces name the kernels ``bam_fwd``, ``bam_bwd_dq`` and
+``bam_bwd_dkv``. The old recompute-through-XLA path survives only as the
 ``impl="xla"`` fallback in ops.py.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,29 +94,54 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bam
 
 NEG_INF = -1e30
+_INT_MAX, _INT_MIN = 2 ** 31 - 1, -2 ** 31
 
 
-def _mask_tile(qb, kb, qp, kp, window: int):
-    """Query-side bitfields/positions [bq, 1] and key-side [1, bk] (int32
-    or uint32) -> [bq, bk] bool. Mirrors repro.core.bam.allowed_mask
-    (tested against it); bits never use bit 31, so int32 is exact."""
-    nonpad = (qb != 0) & (kb != 0)
-    same_doc = bam.instance_id(qb) == bam.instance_id(kb)
-    bit_ok = ((bam.attends_set(qb) >> bam.own_modality(kb)) & 1) != 0
+def _mask_words(q_bits, kv_bits, q_pos, kv_pos, window: int):
+    """Rewrite ``bam.allowed_mask`` as per-token int32 words [B, T]:
+
+        allowed(i, j) = d_i == d_j  and  e_i & o_j != 0
+                        and  p_j <= hi_i  [and  lo_i < p_j]
+
+    with d the instance id; o_j = 1 << m_j for a key that is not
+    padding and whose modality an attends set can name, else 0; e_i the
+    attends set A_i of a text query, A_i & (1 << m_i) of a modality
+    query (which attends its own modality only), 0 for padding;
+    hi_i = p_i for a text query (causal), INT_MAX for a modality query;
+    and, with a sliding window, lo_i = p_i - window for a text query,
+    INT_MIN for a modality query. Exact for every bitfield and every
+    position above INT_MIN. Returns (query words, key words)."""
+    qb = q_bits.astype(jnp.uint32)
+    kb = kv_bits.astype(jnp.uint32)
+
+    def onehot(m):
+        nameable = m < bam.ATTEND_BITS
+        return jnp.where(nameable, jnp.uint32(1) << jnp.where(nameable, m, 0),
+                         0)
+
     q_text = bam.own_modality(qb) == bam.TEXT
-    causal = kp <= qp
+    a = bam.attends_set(qb)
+    e = jnp.where(q_text, a, a & onehot(bam.own_modality(qb)))
+    q_words = [bam.instance_id(qb), jnp.where(qb != 0, e, 0),
+               jnp.where(q_text, q_pos, _INT_MAX)]
     if window:
-        causal &= (qp - kp) < window
-    within = bam.own_modality(kb) == bam.own_modality(qb)
-    # select written as logic: Mosaic cannot lower a where over bools
-    rule = (q_text & causal) | (~q_text & within)
-    return nonpad & same_doc & bit_ok & rule
+        q_words.append(jnp.where(q_text, q_pos - window, _INT_MIN))
+    k_words = [bam.instance_id(kb),
+               jnp.where(kb != 0, onehot(bam.own_modality(kb)), 0), kv_pos]
+    return ([w.astype(jnp.int32) for w in q_words],
+            [w.astype(jnp.int32) for w in k_words])
 
 
-def _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window: int):
-    """The [bq, bk] mask tile from the (1, bq, 1) query-column blocks
-    and the (1, 1, bk) key-row blocks."""
-    return _mask_tile(qb_ref[0], kb_ref[0], qp_ref[0], kp_ref[0], window)
+def _words_mask(q_refs, k_refs):
+    """The mask tile from the words' blocks: query words as (bq, 1)
+    columns and key words as (1, bk) rows give [bq, bk]; query rows and
+    key columns give its transpose [bk, bq]."""
+    qd, qe, qhi, *qlo = (r[0] for r in q_refs)
+    kd, ko, kp = (r[0] for r in k_refs)
+    ok = (qd == kd) & ((qe & ko) != 0) & (kp <= qhi)
+    for lo in qlo:
+        ok &= lo < kp
+    return ok
 
 
 def _col(x):
@@ -110,286 +159,135 @@ def _head_major(x):
     return jnp.swapaxes(x, 1, 2)
 
 
-# ---------------------------------------------------------------------------
-# Forward kernel bodies (shared by the dense and compacted grids)
-# ---------------------------------------------------------------------------
-
-def _fwd_accumulate(allowed, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                    softcap: float, scale: float):
-    q = q_ref[0, 0].astype(jnp.float32)                 # [bq, hd]
-    k = k_ref[0, 0].astype(jnp.float32)                 # [bk, hd]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * scale
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(allowed, s, NEG_INF)
-    m_prev = m_scr[...]                                 # [bq, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(allowed, p, 0.0)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + \
-        jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+def _dot(a, b, contract):
+    """MXU matmul of operands in their own dtype, accumulated in f32."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
-def _fwd_init(m_scr, l_scr, acc_scr):
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-
-def _fwd_finish(mode, out_refs, m_scr, l_scr, acc_scr):
-    m = m_scr[...]                                      # [bq, 1]
-    l = l_scr[...]
-    if mode == "stats":
-        acc_ref, m_ref, l_ref = out_refs
-        acc_ref[0, 0] = acc_scr[...].astype(acc_ref.dtype)
-        m_ref[0, 0] = m
-        l_ref[0, 0] = l
-        return
-    out = acc_scr[...] / jnp.maximum(l, 1e-30)
-    out = jnp.where(l > 0, out, 0.0)
-    if mode == "residual":
-        o_ref, lse_ref = out_refs
-        lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)),
-                                  NEG_INF)
-    else:
-        (o_ref,) = out_refs
-    o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def _bam_fwd_kernel(qb_ref, kb_ref, qp_ref, kp_ref,     # bitfield meta
-                    q_ref, k_ref, v_ref,                # tensors
-                    *refs,                              # outputs + scratch
-                    softcap: float, window: int, nk: int, scale: float,
-                    block_skip: bool, mode: str):
-    out_refs, (m_scr, l_scr, acc_scr) = refs[:-3], refs[-3:]
-    ki = pl.program_id(3)
-
-    pl.when(ki == 0)(lambda: _fwd_init(m_scr, l_scr, acc_scr))
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
-
-    def compute():
-        _fwd_accumulate(allowed, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                        softcap, scale)
-
-    if block_skip:
-        # block sparsity: a fully-masked tile never touches the MXU
-        pl.when(jnp.any(allowed))(compute)
-    else:
-        compute()
-
-    pl.when(ki == nk - 1)(
-        lambda: _fwd_finish(mode, out_refs, m_scr, l_scr, acc_scr))
-
-
-def _bam_fwd_kernel_sparse(qblk_ref, kblk_ref, first_ref, last_ref,
-                           active_ref,                  # scalar prefetch
-                           qb_ref, kb_ref, qp_ref, kp_ref,
-                           q_ref, k_ref, v_ref,
-                           *refs,
-                           softcap: float, window: int, scale: float,
-                           block_skip: bool, mode: str):
-    """Grid-compacted forward: grid (B, H, n_steps); the active-tile list
-    (host-precomputed) drives the index maps, init and flush."""
-    out_refs, (m_scr, l_scr, acc_scr) = refs[:-3], refs[-3:]
-    t = pl.program_id(2)
-
-    pl.when(first_ref[t] == 1)(lambda: _fwd_init(m_scr, l_scr, acc_scr))
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
-    is_active = active_ref[t] == 1
-
-    def compute():
-        _fwd_accumulate(allowed, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                        softcap, scale)
-
-    if block_skip:
-        pl.when(is_active & jnp.any(allowed))(compute)
-    else:
-        pl.when(is_active)(compute)
-
-    pl.when(last_ref[t] == 1)(
-        lambda: _fwd_finish(mode, out_refs, m_scr, l_scr, acc_scr))
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
 
 
 # ---------------------------------------------------------------------------
-# Backward kernel bodies
+# Grids: what the kernel bodies and index maps need to know of the grid
 # ---------------------------------------------------------------------------
 
-def _recompute_p_ds(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    softcap: float, scale: float):
-    """Recompute the probability tile from (q, k, lse) and form
-    dS = P * (dP - delta), with the softcap chain rule folded in.
-    Returns (p [bq,bk], ds [bq,bk], q, k, do) all f32."""
-    q = q_ref[0, 0].astype(jnp.float32)                 # [bq, hd]
-    k = k_ref[0, 0].astype(jnp.float32)                 # [bk, hd]
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                 # [bq, 1]
-    delta = delta_ref[0, 0]                             # [bq, 1]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    p = jnp.where(allowed, jnp.exp(s - lse), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    if softcap:
-        ds = ds * (1.0 - (s / softcap) ** 2)
-    return p, ds, q, k, do
+def _tile_word(tiles_ref, b, outer, inner, n_outer: int, n_inner: int):
+    """Tile (outer, inner) of batch row ``b`` in a dense grid's flattened
+    [B, n_outer, n_inner] scalar prefetch (SMEM)."""
+    return tiles_ref[(b * n_outer + outer) * n_inner + inner]
 
 
-def _dq_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_scr, softcap: float, scale: float):
-    _, ds, _, k, _ = _recompute_p_ds(
-        allowed, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-        softcap, scale)
-    dq_scr[...] += jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-
-
-def _bam_bwd_dq_kernel(qb_ref, kb_ref, qp_ref, kp_ref,
-                       q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dq_ref, dq_scr, *, softcap: float, window: int,
-                       nk: int, scale: float, block_skip: bool):
-    ki = pl.program_id(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
-
-    def compute():
-        _dq_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_scr, softcap, scale)
-
+def _pack_tiles(cls, block_skip: bool):
+    """A dense grid's scalar prefetch from the tile classes
+    cls [B, n_outer, n_inner] (inner: the grid's arbitrary axis): each
+    tile's word is ``fetch << 2 | class``. ``fetch`` is the inner block
+    whose operands the tile's step loads: its own, or for a skipped
+    empty tile the nearest tile of its row that computes, so that the
+    pipeline issues no copy for it. Flattened for SMEM."""
+    n = cls.shape[-1]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    fetch = jnp.broadcast_to(idx, cls.shape)
     if block_skip:
-        pl.when(jnp.any(allowed))(compute)
-    else:
-        compute()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+        active = cls != bam.TILE_EMPTY
+        before = lax.cummax(jnp.where(active, idx, -1), axis=2)
+        after = lax.cummin(jnp.where(active, idx, n), axis=2, reverse=True)
+        fetch = jnp.where(before >= 0, before, jnp.where(after < n, after, 0))
+    return ((fetch << 2) | cls).reshape(-1)
 
 
-def _bam_bwd_dq_kernel_sparse(qblk_ref, kblk_ref, first_ref, last_ref,
-                              active_ref,
-                              qb_ref, kb_ref, qp_ref, kp_ref,
-                              q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, dq_ref, dq_scr, *,
-                              softcap: float, window: int, scale: float,
-                              block_skip: bool):
-    t = pl.program_id(2)
-
-    @pl.when(first_ref[t] == 1)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
-    is_active = active_ref[t] == 1
-
-    def compute():
-        _dq_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_scr, softcap, scale)
-
-    if block_skip:
-        pl.when(is_active & jnp.any(allowed))(compute)
-    else:
-        pl.when(is_active)(compute)
-
-    @pl.when(last_ref[t] == 1)
-    def _finish():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+def _dispatch(cls, compute, mask, block_skip: bool):
+    """Run ``compute(allowed)`` on a dense-grid tile by its class: a full
+    tile passes no mask, a partial one the in-register mask; an empty
+    one is skipped (masked like a partial one without ``block_skip``)."""
+    pl.when(cls == bam.TILE_FULL)(lambda: compute(None))
+    partial = (cls == bam.TILE_PARTIAL) if block_skip else \
+        (cls != bam.TILE_FULL)
+    pl.when(partial)(lambda: compute(mask()))
 
 
-def _dkv_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_scr, dv_scr, softcap: float, scale: float):
-    p, ds, q, _, do = _recompute_p_ds(
-        allowed, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-        softcap, scale)
-    dv_scr[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_scr[...] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+class _Dense:
+    """The full grid. q-major (forward, dQ): (B, H, nq, nk). k-major
+    (dK/dV): (B, Hkv, nk, n_rep * nq), whose step j walks q block
+    j % nq of query head g * n_rep + j // nq — the GQA fold. One
+    scalar-prefetch array of packed tile words (``_pack_tiles``)."""
+
+    n_prefetch = 1
+
+    def __init__(self, B, heads, nq, nk, n_rep, q_major, block_skip):
+        self.nq, self.nk, self.n_rep = nq, nk, n_rep
+        self.q_major, self.block_skip = q_major, block_skip
+        inner = nk if q_major else n_rep * nq
+        self.shape = (B, heads, nq if q_major else nk, inner)
+        self.semantics = ("parallel", "parallel", "parallel", "arbitrary")
+        self.folds_gqa = not q_major
+
+    def prefetch(self, q_bits, kv_bits, q_pos, kv_pos, block_q, block_k,
+                 window):
+        cls = bam.tile_classes(q_bits, kv_bits, q_pos, kv_pos, block_q,
+                               block_k, window)
+        if not self.q_major:
+            cls = jnp.swapaxes(cls, 1, 2)
+        return (_pack_tiles(cls, self.block_skip),)
+
+    def _word(self, tiles, b, i, j):
+        if self.q_major:
+            return _tile_word(tiles, b, i, j, self.nq, self.nk)
+        return _tile_word(tiles, b, i, j % self.nq, self.nk, self.nq)
+
+    def blocks(self, b, h, i, j, tiles):
+        """Grid indices -> (b, query head, q block, kv head, k block)."""
+        fetch = self._word(tiles, b, i, j) >> 2
+        if self.q_major:
+            return b, h, i, h // self.n_rep, fetch
+        return b, h * self.n_rep + j // self.nq, fetch, h, i
+
+    def first(self, refs):
+        return pl.program_id(3) == 0
+
+    def last(self, refs):
+        return pl.program_id(3) == self.shape[3] - 1
+
+    def run(self, refs, compute, mask):
+        word = self._word(refs[0], pl.program_id(0), pl.program_id(2),
+                          pl.program_id(3))
+        _dispatch(word & 3, compute, mask, self.block_skip)
 
 
-def _bam_bwd_dkv_kernel(qb_ref, kb_ref, qp_ref, kp_ref,
-                        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        softcap: float, window: int, nq: int, scale: float,
-                        block_skip: bool):
-    """Transposed grid (B, H, nk, nq): the arbitrary dimension iterates
-    q blocks; dK/dV accumulate per k block."""
-    qi = pl.program_id(3)
+class _Compacted:
+    """The grid of a host-built block map: (B, H, n_steps) over its
+    active tiles in q-major or k-major order, five scalar-prefetch step
+    arrays (q block, k block, first, last, active)."""
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    n_prefetch = 5
 
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
+    def __init__(self, block_map, major, B, H, n_rep, block_skip):
+        self.arrays = tuple(jnp.asarray(a) for a in block_map.arrays(major))
+        self.n_rep, self.block_skip = n_rep, block_skip
+        self.shape = (B, H, len(self.arrays[0]))
+        self.semantics = ("parallel", "parallel", "arbitrary")
+        self.folds_gqa = False
 
-    def compute():
-        _dkv_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_scr, dv_scr, softcap, scale)
+    def prefetch(self, *_):
+        return self.arrays
 
-    if block_skip:
-        pl.when(jnp.any(allowed))(compute)
-    else:
-        compute()
+    def blocks(self, b, h, t, qblk, kblk, first, last, active):
+        return b, h, qblk[t], h // self.n_rep, kblk[t]
 
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+    def first(self, refs):
+        return refs[2][pl.program_id(2)] == 1
 
+    def last(self, refs):
+        return refs[3][pl.program_id(2)] == 1
 
-def _bam_bwd_dkv_kernel_sparse(qblk_ref, kblk_ref, first_ref, last_ref,
-                               active_ref,
-                               qb_ref, kb_ref, qp_ref, kp_ref,
-                               q_ref, k_ref, v_ref, do_ref, lse_ref,
-                               delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                               softcap: float, window: int, scale: float,
-                               block_skip: bool):
-    t = pl.program_id(2)
+    def run(self, refs, compute, mask):
+        allowed = mask()
+        go = refs[4][pl.program_id(2)] == 1
+        if self.block_skip:
+            go &= jnp.any(allowed)
+        pl.when(go)(lambda: compute(allowed))
 
-    @pl.when(first_ref[t] == 1)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    allowed = _tile_mask(qb_ref, kb_ref, qp_ref, kp_ref, window)
-    is_active = active_ref[t] == 1
-
-    def compute():
-        _dkv_accumulate(allowed, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_scr, dv_scr, softcap, scale)
-
-    if block_skip:
-        pl.when(is_active & jnp.any(allowed))(compute)
-    else:
-        pl.when(is_active)(compute)
-
-    @pl.when(last_ref[t] == 1)
-    def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
-# pallas_call wrappers
-# ---------------------------------------------------------------------------
 
 def _check_block_map(block_map, block_q, block_k, nq, nk, window):
     assert block_map.block_q == block_q and block_map.block_k == block_k, \
@@ -404,81 +302,232 @@ def _check_block_map(block_map, block_q, block_k, nq, nk, window):
          block_map.window, window)
 
 
-def _prefetch_arrays(block_map, major):
-    return tuple(jnp.asarray(a) for a in block_map.arrays(major))
+def _grid(block_map, major, B, H, Hkv, nq, nk, block_q, block_k, window,
+          block_skip):
+    n_rep = H // Hkv
+    if block_map is None:
+        heads = H if major == "q" else Hkv
+        return _Dense(B, heads, nq, nk, n_rep, major == "q", block_skip)
+    _check_block_map(block_map, block_q, block_k, nq, nk, window)
+    return _Compacted(block_map, major, B, H, n_rep, block_skip)
 
 
-def _dense_index_maps(n_rep: int, q_major: bool):
-    """Index maps for the dense 4-D grids: (b, h, iq, ik) for the q-major
-    forward/dQ grids, (b, h, ik, iq) for the k-major dK/dV grid."""
+def _specs(grid, block_q, block_k, hd, n_qwords, kind):
+    """BlockSpecs of the operands in call order — query words, key
+    words, q, k, v and, for the backward, dO, lse, delta — plus the
+    index maps of the q tile, the dK/dV output tile and the statistics
+    column. ``kind``: "fwd" / "dq" (query words as columns, key words as
+    rows) or "dkv" (the transpose, statistics as rows)."""
+    blocks = grid.blocks
 
-    def order(i, j):
-        return (i, j) if q_major else (j, i)
+    def qcol(*a):
+        return (a[0], blocks(*a)[2], 0)
 
-    def qm(b, h, i, j):
-        return (b, order(i, j)[0], 0)
+    def qrow(*a):
+        return (a[0], 0, blocks(*a)[2])
 
-    def km(b, h, i, j):
-        return (b, 0, order(i, j)[1])
+    def kcol(*a):
+        return (a[0], blocks(*a)[4], 0)
 
-    def qtile(b, h, i, j):
-        return (b, h, order(i, j)[0], 0)
+    def krow(*a):
+        return (a[0], 0, blocks(*a)[4])
 
-    def ktile(b, h, i, j):
-        return (b, h // n_rep, order(i, j)[1], 0)
+    def qtile(*a):
+        b, hq, qb, _, _ = blocks(*a)
+        return (b, hq, qb, 0)
 
-    def ktile_full(b, h, i, j):
-        return (b, h, order(i, j)[1], 0)
+    def ktile(*a):
+        b, _, _, hkv, kb = blocks(*a)
+        return (b, hkv, kb, 0)
 
-    return qm, km, qtile, ktile, ktile_full
+    def dk_tile(*a):
+        b, hq, _, hkv, kb = blocks(*a)
+        return (b, hkv if grid.folds_gqa else hq, kb, 0)
 
+    def stat_row(*a):
+        b, hq, qb, _, _ = blocks(*a)
+        return (b, hq, 0, qb)
 
-def _sparse_index_maps(n_rep: int):
-    """Index maps for the compacted (B, H, n_steps) grids. All receive
-    (b, h, t, *scalar_prefetch_refs); the step arrays address the
-    blocks. Shared by forward and backward so the prefetch layout can
-    only change in one place."""
-
-    def qm(b, h, t, qblk, kblk, first, last, active):
-        return (b, qblk[t], 0)
-
-    def km(b, h, t, qblk, kblk, first, last, active):
-        return (b, 0, kblk[t])
-
-    def qtile(b, h, t, qblk, kblk, first, last, active):
-        return (b, h, qblk[t], 0)
-
-    def ktile(b, h, t, qblk, kblk, first, last, active):
-        return (b, h // n_rep, kblk[t], 0)
-
-    def ktile_full(b, h, t, qblk, kblk, first, last, active):
-        return (b, h, kblk[t], 0)
-
-    return qm, km, qtile, ktile, ktile_full
-
-
-def _in_specs(maps, block_q, block_k, hd, bwd: bool):
-    """BlockSpecs of the operands (q-meta x2, k-meta x2, q, k, v and, for
-    the backward, dO, lse, delta) in call order. Per-row statistics are
-    (bq, 1) columns of [B, H, Tq, 1], addressed like the q tiles."""
-    qm, km, qtile, ktile, _ = maps
-    specs = [pl.BlockSpec((1, block_q, 1), qm),
-             pl.BlockSpec((1, 1, block_k), km),
-             pl.BlockSpec((1, block_q, 1), qm),
-             pl.BlockSpec((1, 1, block_k), km),
-             pl.BlockSpec((1, 1, block_q, hd), qtile),
-             pl.BlockSpec((1, 1, block_k, hd), ktile),
-             pl.BlockSpec((1, 1, block_k, hd), ktile)]
-    if bwd:
-        specs += [pl.BlockSpec((1, 1, block_q, hd), qtile),
-                  pl.BlockSpec((1, 1, block_q, 1), qtile),
-                  pl.BlockSpec((1, 1, block_q, 1), qtile)]
-    return specs
+    if kind == "dkv":
+        qw = pl.BlockSpec((1, 1, block_q), qrow)
+        kw = pl.BlockSpec((1, block_k, 1), kcol)
+        stat = pl.BlockSpec((1, 1, 1, block_q), stat_row)
+    else:
+        qw = pl.BlockSpec((1, block_q, 1), qcol)
+        kw = pl.BlockSpec((1, 1, block_k), krow)
+        stat = pl.BlockSpec((1, 1, block_q, 1), qtile)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd), qtile)
+    k_spec = pl.BlockSpec((1, 1, block_k, hd), ktile)
+    specs = [qw] * n_qwords + [kw] * 3 + [q_spec, k_spec, k_spec]
+    if kind != "fwd":
+        specs += [q_spec, stat, stat]
+    return specs, qtile, dk_tile
 
 
-def _meta_operands(q_bits, kv_bits, q_pos, kv_pos):
-    return (_col(q_bits), _row(kv_bits), _col(q_pos), _row(kv_pos))
+def _call(kernel, grid, prefetch, in_specs, out_specs, out_shape, scratch,
+          block_q: int, block_k: int, interpret, name: str):
+    # tiles past 256 x 256 hold several f32 [bq, bk] temporaries and get
+    # a larger scoped-VMEM budget
+    vmem = 64 * 2 ** 20 if block_q * block_k > 256 * 256 else None
+    return pl.pallas_call(
+        functools.partial(kernel, grid=grid),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=grid.shape,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=grid.semantics, vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name=name,
+    )
 
+
+def _unpack(refs, grid, n_qwords: int, n_tensors: int):
+    """Kernel refs -> (prefetch, query words, key words, tensors, rest)."""
+    p = grid.n_prefetch
+    i = p + n_qwords
+    return (refs[:p], refs[p:i], refs[i:i + 3], refs[i + 3:i + 3 + n_tensors],
+            refs[i + 3 + n_tensors:])
+
+
+# ---------------------------------------------------------------------------
+# Kernel bodies
+# ---------------------------------------------------------------------------
+
+def _fwd_accumulate(allowed, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                    softcap: float, scale: float):
+    """One tile of the online softmax; ``allowed`` is the [bq, bk] mask
+    or None for a tile whose every pair is allowed. A row that has met
+    no allowed key keeps m = NEG_INF and takes p = 1 on masked entries;
+    the first allowed key rescales them by alpha = 0, and
+    ``_fwd_finish`` zeroes a row that meets none."""
+    v = v_ref[0, 0]                                     # [bk, hd]
+    s = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale     # [bq, bk] f32
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    if allowed is not None:
+        s = jnp.where(allowed, s, NEG_INF)
+    m_prev = m_scr[...]                                 # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + _dot(p.astype(v.dtype), v, _NN)
+    m_scr[...] = m_new
+
+
+def _fwd_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _fwd_finish(mode, out_refs, m_scr, l_scr, acc_scr):
+    m = m_scr[...]                                      # [bq, 1]
+    live = m > NEG_INF / 2                              # met an allowed key
+    l = jnp.where(live, l_scr[...], 0.0)
+    if mode == "stats":
+        acc_ref, m_ref, l_ref = out_refs
+        acc_ref[0, 0] = jnp.where(live, acc_scr[...], 0.0).astype(
+            acc_ref.dtype)
+        m_ref[0, 0] = m
+        l_ref[0, 0] = l
+        return
+    out = jnp.where(live, acc_scr[...] / jnp.maximum(l, 1e-30), 0.0)
+    if mode == "residual":
+        o_ref, lse_ref = out_refs
+        lse_ref[0, 0] = jnp.where(live, m + jnp.log(jnp.maximum(l, 1e-30)),
+                                  NEG_INF)
+    else:
+        (o_ref,) = out_refs
+    o_ref[0, 0] = out.astype(o_ref.dtype)
+
+
+def _fwd_kernel(*refs, grid, n_qwords: int, softcap: float, scale: float,
+                mode: str):
+    pre, qw, kw, (q_ref, k_ref, v_ref), rest = _unpack(refs, grid,
+                                                       n_qwords, 3)
+    out_refs, (m_scr, l_scr, acc_scr) = rest[:-3], rest[-3:]
+    pl.when(grid.first(pre))(lambda: _fwd_init(m_scr, l_scr, acc_scr))
+    grid.run(pre,
+             lambda allowed: _fwd_accumulate(allowed, q_ref, k_ref, v_ref,
+                                             m_scr, l_scr, acc_scr,
+                                             softcap, scale),
+             lambda: _words_mask(qw, kw))
+    pl.when(grid.last(pre))(
+        lambda: _fwd_finish(mode, out_refs, m_scr, l_scr, acc_scr))
+
+
+def _p_ds(allowed, a, b, da, db, lse, delta, softcap: float, scale: float):
+    """Recompute one tile's probabilities P = exp(a·bᵀ·scale − lse) and
+    form dS = P ∘ (da·dbᵀ − delta), with the softcap chain rule folded
+    in; lse and delta broadcast against the tile (columns in the dQ
+    orientation, rows in the transposed dK/dV one). f32 results;
+    ``allowed`` None means a tile whose every pair is allowed."""
+    s = _dot(a, b, _NT) * scale
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    p = jnp.exp(s - lse)
+    if allowed is not None:
+        p = jnp.where(allowed, p, 0.0)
+    ds = p * (_dot(da, db, _NT) - delta)
+    if softcap:
+        ds = ds * (1.0 - (s / softcap) ** 2)
+    return p, ds
+
+
+def _dq_kernel(*refs, grid, n_qwords: int, softcap: float, scale: float):
+    pre, qw, kw, tensors, (dq_ref, dq_scr) = _unpack(refs, grid, n_qwords, 6)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = tensors
+
+    @pl.when(grid.first(pre))
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def compute(allowed):
+        k = k_ref[0, 0]
+        _, ds = _p_ds(allowed, q_ref[0, 0], k, do_ref[0, 0], v_ref[0, 0],
+                      lse_ref[0, 0], delta_ref[0, 0], softcap, scale)
+        dq_scr[...] += _dot(ds.astype(k.dtype), k, _NN) * scale
+
+    grid.run(pre, compute, lambda: _words_mask(qw, kw))
+
+    @pl.when(grid.last(pre))
+    def _finish():
+        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(*refs, grid, n_qwords: int, softcap: float, scale: float):
+    """dK/dV on transposed [bk, bq] tiles: Sᵀ = K Qᵀ, dPᵀ = V dOᵀ,
+    dV += Pᵀ dO, dK += dSᵀ Q — no matmul takes a transposed operand."""
+    pre, qw, kw, tensors, rest = _unpack(refs, grid, n_qwords, 6)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = tensors
+    dk_ref, dv_ref, dk_scr, dv_scr = rest
+
+    @pl.when(grid.first(pre))
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def compute(allowed):
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        p, ds = _p_ds(allowed, k_ref[0, 0], q, v_ref[0, 0], do,
+                      lse_ref[0, 0], delta_ref[0, 0], softcap, scale)
+        dv_scr[...] += _dot(p.astype(do.dtype), do, _NN)
+        dk_scr[...] += _dot(ds.astype(q.dtype), q, _NN) * scale
+
+    grid.run(pre, compute, lambda: _words_mask(qw, kw))
+
+    @pl.when(grid.last(pre))
+    def _finish():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Public wrappers
+# ---------------------------------------------------------------------------
 
 def bam_flash_attention(q, k, v, q_bits, kv_bits, q_pos, kv_pos, *,
                         softcap: float = 0.0, window: int = 0,
@@ -498,9 +547,14 @@ def bam_flash_attention(q, k, v, q_bits, kv_bits, q_pos, kv_pos, *,
     B, Tq, H, hd = q.shape
     _, Tk, Hkv, _ = k.shape
     assert H % Hkv == 0
-    n_rep = H // Hkv
     assert Tq % block_q == 0 and Tk % block_k == 0, (Tq, Tk)
-    nq, nk = Tq // block_q, Tk // block_k
+    grid = _grid(block_map, "q", B, H, Hkv, Tq // block_q, Tk // block_k,
+                 block_q, block_k, window, block_skip)
+    prefetch = grid.prefetch(q_bits, kv_bits, q_pos, kv_pos, block_q,
+                             block_k, window)
+    q_words, k_words = _mask_words(q_bits, kv_bits, q_pos, kv_pos, window)
+    in_specs, qtile, _ = _specs(grid, block_q, block_k, hd, len(q_words),
+                                "fwd")
 
     row = jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32)
     out_shapes = {
@@ -509,45 +563,19 @@ def bam_flash_attention(q, k, v, q_bits, kv_bits, q_pos, kv_pos, *,
         "stats": (jax.ShapeDtypeStruct((B, H, Tq, hd), jnp.float32),
                   row, row),
     }[return_mode]
-    scratch = [
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, hd), jnp.float32),
-    ]
-    common = dict(softcap=softcap, window=window, scale=hd ** -0.5,
-                  block_skip=block_skip, mode=return_mode)
-    operands = (*_meta_operands(q_bits, kv_bits, q_pos, kv_pos),
-                _head_major(q), _head_major(k), _head_major(v))
-
-    if block_map is None:
-        maps = _dense_index_maps(n_rep, q_major=True)
-        kernel = functools.partial(_bam_fwd_kernel, nk=nk, **common)
-        prefetch = ()
-        grid = (B, H, nq, nk)
-        semantics = ("parallel", "parallel", "parallel", "arbitrary")
-    else:
-        _check_block_map(block_map, block_q, block_k, nq, nk, window)
-        maps = _sparse_index_maps(n_rep)
-        kernel = functools.partial(_bam_fwd_kernel_sparse, **common)
-        prefetch = _prefetch_arrays(block_map, "q")
-        grid = (B, H, block_map.n_steps)
-        semantics = ("parallel", "parallel", "arbitrary")
-    qtile = maps[2]
     out_specs = [pl.BlockSpec((1, 1, block_q, hd), qtile)] + \
         [pl.BlockSpec((1, 1, block_q, 1), qtile)] * (len(out_shapes) - 1)
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=_in_specs(maps, block_q, block_k, hd, bwd=False),
-            out_specs=out_specs,
-            scratch_shapes=scratch,
-        ),
-        out_shape=list(out_shapes),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
-        interpret=interpret,
-    )(*prefetch, *operands)
+    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, hd), jnp.float32)]
+    kernel = functools.partial(_fwd_kernel, n_qwords=len(q_words),
+                               softcap=softcap, scale=hd ** -0.5,
+                               mode=return_mode)
+    outs = _call(kernel, grid, prefetch, in_specs, out_specs,
+                 list(out_shapes), scratch, block_q, block_k, interpret,
+                 "bam_fwd")(
+        *prefetch, *map(_col, q_words), *map(_row, k_words),
+        _head_major(q), _head_major(k), _head_major(v))
 
     if return_mode == "stats":
         acc, m, l = outs
@@ -570,75 +598,53 @@ def bam_flash_attention_bwd(q, k, v, out, do, lse, q_bits, kv_bits, q_pos,
     [B, Tk, Hkv, hd]."""
     B, Tq, H, hd = q.shape
     _, Tk, Hkv, _ = k.shape
-    n_rep = H // Hkv
     assert Tq % block_q == 0 and Tk % block_k == 0, (Tq, Tk)
     nq, nk = Tq // block_q, Tk // block_k
-    scale = hd ** -0.5
 
     # delta_i = sum_d dO_i·O_i — the rowwise correction term (O(T·hd))
     delta = jnp.einsum("bqhd,bqhd->bhq", out.astype(jnp.float32),
                        do.astype(jnp.float32))
+    lse = lse.astype(jnp.float32)
+    q_words, k_words = _mask_words(q_bits, kv_bits, q_pos, kv_pos, window)
+    tensors = (_head_major(q), _head_major(k), _head_major(v),
+               _head_major(do))
+    common = dict(n_qwords=len(q_words), softcap=softcap, scale=hd ** -0.5)
+    tiles = (q_bits, kv_bits, q_pos, kv_pos, block_q, block_k, window)
 
-    common = dict(softcap=softcap, window=window, scale=scale,
-                  block_skip=block_skip)
-    operands = (*_meta_operands(q_bits, kv_bits, q_pos, kv_pos),
-                _head_major(q), _head_major(k), _head_major(v),
-                _head_major(do), _col(lse.astype(jnp.float32)), _col(delta))
-    dk_shape = jax.ShapeDtypeStruct((B, H, Tk, hd), jnp.float32)
+    grid = _grid(block_map, "q", B, H, Hkv, nq, nk, block_q, block_k,
+                 window, block_skip)
+    prefetch = grid.prefetch(*tiles)
+    in_specs, qtile, _ = _specs(grid, block_q, block_k, hd, len(q_words),
+                                "dq")
+    dq = _call(functools.partial(_dq_kernel, **common), grid, prefetch,
+               in_specs, pl.BlockSpec((1, 1, block_q, hd), qtile),
+               jax.ShapeDtypeStruct((B, H, Tq, hd), q.dtype),
+               [pltpu.VMEM((block_q, hd), jnp.float32)],
+               block_q, block_k, interpret, "bam_bwd_dq")(
+        *prefetch, *map(_col, q_words), *map(_row, k_words), *tensors,
+        _col(lse), _col(delta))
 
-    if block_map is None:
-        q_maps = _dense_index_maps(n_rep, q_major=True)
-        k_maps = _dense_index_maps(n_rep, q_major=False)
-        dq_kernel = functools.partial(_bam_bwd_dq_kernel, nk=nk, **common)
-        dkv_kernel = functools.partial(_bam_bwd_dkv_kernel, nq=nq, **common)
-        q_prefetch = k_prefetch = ()
-        q_grid, k_grid = (B, H, nq, nk), (B, H, nk, nq)
-        semantics = ("parallel", "parallel", "parallel", "arbitrary")
-    else:
-        _check_block_map(block_map, block_q, block_k, nq, nk, window)
-        q_maps = k_maps = _sparse_index_maps(n_rep)
-        dq_kernel = functools.partial(_bam_bwd_dq_kernel_sparse, **common)
-        dkv_kernel = functools.partial(_bam_bwd_dkv_kernel_sparse, **common)
-        q_prefetch = _prefetch_arrays(block_map, "q")
-        k_prefetch = _prefetch_arrays(block_map, "k")
-        q_grid = (B, H, block_map.n_steps)
-        k_grid = (B, H, len(block_map.k_steps))
-        semantics = ("parallel", "parallel", "arbitrary")
-    params = pltpu.CompilerParams(dimension_semantics=semantics)
+    grid = _grid(block_map, "k", B, H, Hkv, nq, nk, block_q, block_k,
+                 window, block_skip)
+    prefetch = grid.prefetch(*tiles)
+    in_specs, _, dk_tile = _specs(grid, block_q, block_k, hd, len(q_words),
+                                  "dkv")
+    dk_shape = jax.ShapeDtypeStruct((B, Hkv, Tk, hd), k.dtype) \
+        if grid.folds_gqa else jax.ShapeDtypeStruct((B, H, Tk, hd),
+                                                    jnp.float32)
+    dk_h, dv_h = _call(functools.partial(_dkv_kernel, **common), grid,
+                       prefetch, in_specs,
+                       [pl.BlockSpec((1, 1, block_k, hd), dk_tile)] * 2,
+                       [dk_shape, dk_shape],
+                       [pltpu.VMEM((block_k, hd), jnp.float32)] * 2,
+                       block_q, block_k, interpret, "bam_bwd_dkv")(
+        *prefetch, *map(_row, q_words), *map(_col, k_words), *tensors,
+        _row(lse), _row(delta))
 
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(q_prefetch),
-            grid=q_grid,
-            in_specs=_in_specs(q_maps, block_q, block_k, hd, bwd=True),
-            out_specs=pl.BlockSpec((1, 1, block_q, hd), q_maps[2]),
-            scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, hd), q.dtype),
-        compiler_params=params,
-        interpret=interpret,
-    )(*q_prefetch, *operands)
-
-    ktile_full = k_maps[4]
-    dk_h, dv_h = pl.pallas_call(
-        dkv_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(k_prefetch),
-            grid=k_grid,
-            in_specs=_in_specs(k_maps, block_q, block_k, hd, bwd=True),
-            out_specs=[pl.BlockSpec((1, 1, block_k, hd), ktile_full),
-                       pl.BlockSpec((1, 1, block_k, hd), ktile_full)],
-            scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                            pltpu.VMEM((block_k, hd), jnp.float32)],
-        ),
-        out_shape=[dk_shape, dk_shape],
-        compiler_params=params,
-        interpret=interpret,
-    )(*k_prefetch, *operands)
-
-    # GQA: fold q-head grads back onto shared KV heads
-    dk_h = dk_h.reshape(B, Hkv, n_rep, Tk, hd).sum(axis=2)
-    dv_h = dv_h.reshape(B, Hkv, n_rep, Tk, hd).sum(axis=2)
+    if not grid.folds_gqa:
+        # GQA: fold the compacted grid's q-head grads onto the KV heads
+        n_rep = H // Hkv
+        dk_h = dk_h.reshape(B, Hkv, n_rep, Tk, hd).sum(axis=2)
+        dv_h = dv_h.reshape(B, Hkv, n_rep, Tk, hd).sum(axis=2)
     return (_head_major(dq), _head_major(dk_h).astype(k.dtype),
             _head_major(dv_h).astype(v.dtype))

@@ -1,9 +1,11 @@
 """jit'd public wrapper for BAM attention.
 
 Dispatch:
-  impl="xla"           — fused-XLA reference math (production dry-run
-                         path on this CPU container; GSPMD-sharded)
-  impl="bam_kernel"    — Pallas TPU kernels (real hardware)
+  impl="xla"           — fused-XLA reference math (CPU backends and
+                         GSPMD dry-runs)
+  impl="bam_kernel"    — Pallas TPU kernels (real hardware; what the
+                         models pick on TPU, models.layers
+                         .resolve_attn_impl)
   impl="bam_interpret" — Pallas kernel bodies interpreted on CPU
                          (correctness validation; what tests sweep)
 
@@ -173,6 +175,24 @@ def bam_attention(q, k, v, q_bits, kv_bits, q_pos=None, kv_pos=None, *,
 def auto_block(T: int, cap: int = 128) -> int:
     """Tile size for short sequences: next multiple of 16, capped."""
     return min(cap, -(-T // 16) * 16)
+
+
+def flash_blocks(Tq: int, Tk: int) -> tuple:
+    """(block_q, block_k) of the training path's kernels, from the
+    lengths alone: for each, the largest multiple of 128 up to 1024 that
+    splits the length into at least two tiles and pads it by under an
+    eighth; short sequences take ``auto_block``. On a v5e this picks
+    1024 at 4096 tokens and 896 at 1600 (padded to 1792), the fastest
+    square tiles of a sweep at both lengths: per-step overhead and the
+    per-element softmax work outweigh the masked elements a large tile
+    computes."""
+    def pick(T):
+        for b in range(1024, 127, -128):
+            n = -(-T // b)
+            if n >= 2 and (n * b - T) * 8 < T:
+                return b
+        return auto_block(T)
+    return pick(Tq), pick(Tk)
 
 
 def bam_attention_stats(q, k, v, q_bits, kv_bits, q_pos=None, kv_pos=None, *,

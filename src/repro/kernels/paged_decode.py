@@ -20,8 +20,7 @@ GQA: a step scores the n_rep query heads that share a KV head as one
 (n_rep, hd) tile against the page's (page_size, hd) tile of the
 head-major pool [P, Hkv, page_size, hd] — no head-expanded K/V ever
 materializes. The mask is evaluated in-registers from the bitfields via
-the training kernels' ``_mask_tile`` (one [1, page_size] tile of it
-lives in VREGs per step).
+``_mask_tile`` (one [1, page_size] tile of it lives in VREGs per step).
 Softcap and sliding window are static params; ``window`` constrains
 text queries only, mirroring ``bam.allowed_mask``.
 
@@ -48,8 +47,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bam_attention import _mask_tile, NEG_INF
+from repro.core import bam
+from repro.kernels.bam_attention import NEG_INF
 from repro.kernels.ref import bam_attention_ref
+
+
+def _mask_tile(qb, kb, qp, kp, window: int):
+    """Query-side bitfields/positions [n, 1] and key-side [1, page_size]
+    (int32) -> [n, page_size] bool. Mirrors repro.core.bam.allowed_mask;
+    bits never use bit 31, so int32 is exact.
+
+    The training kernels rewrite the bitfields into per-token words in
+    XLA once per call (``bam_attention._mask_words``) and reuse each
+    word across a row or column of tiles. Decode has no such reuse: the
+    pool holds raw bitfields and positions per slot, written by prefill
+    and every decode step, and each page tile meets one query row once.
+    Rewriting there would cost about as many operations as this mask."""
+    nonpad = (qb != 0) & (kb != 0)
+    same_doc = bam.instance_id(qb) == bam.instance_id(kb)
+    bit_ok = ((bam.attends_set(qb) >> bam.own_modality(kb)) & 1) != 0
+    q_text = bam.own_modality(qb) == bam.TEXT
+    causal = kp <= qp
+    if window:
+        causal &= (qp - kp) < window
+    within = bam.own_modality(kb) == bam.own_modality(qb)
+    # select written as logic: Mosaic cannot lower a where over bools
+    rule = (q_text & causal) | (~q_text & within)
+    return nonpad & same_doc & bit_ok & rule
 
 
 # ---------------------------------------------------------------------------

@@ -211,6 +211,19 @@ def attn_project_qkv(p: Params, cfg: ModelConfig, x_q, x_kv):
     return q, k, v
 
 
+def resolve_attn_impl(cfg: ModelConfig, bits, kv_override=None) -> str:
+    """The attention implementation of one call: XLA without BAM bits
+    (the encoders' full masks, the gemma2 traced-window layers) and on
+    the decode path (``kv_override``); else ``cfg.attn_impl`` when set
+    explicitly; else, by default, the fused BAM kernel on TPU and XLA on
+    other backends."""
+    if bits is None or kv_override is not None:
+        return "xla"
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    return "bam_kernel" if jax.default_backend() == "tpu" else "xla"
+
+
 def run_attention(p: Params, cfg: ModelConfig, x_q, *, x_kv=None, q_pos=None,
                   kv_pos=None, mask=None, mask_fn=None, rope: bool = True,
                   pos3=None, window: int = 0, kv_override=None, bits=None,
@@ -220,19 +233,22 @@ def run_attention(p: Params, cfg: ModelConfig, x_q, *, x_kv=None, q_pos=None,
     (cfg.attn_q_chunk) without materializing the full mask.
 
     kv_override: (k, v) already-projected cache tensors (decode path).
-    bits/kv_bits: BAM bitfields [B,T*]; when given and cfg.attn_impl is
-    a kernel impl ("bam_kernel" / "bam_interpret"), attention dispatches
-    to the fused Pallas path (repro.kernels.ops.bam_attention — mask
-    in-registers, LSE residuals, fused backward) with ``window`` as the
-    static sliding window. The decode path (kv_override) stays on XLA.
+    bits/kv_bits: BAM bitfields [B,T*]; when given and
+    ``resolve_attn_impl`` picks a kernel impl (by default on TPU),
+    attention dispatches to the fused Pallas path
+    (repro.kernels.ops.bam_attention — mask in-registers, LSE
+    residuals, fused backward) with ``window`` as the static sliding
+    window and tiles from ``ops.flash_blocks``. The decode path
+    (kv_override) stays on XLA.
 
     Context parallelism: when ``cfg.cp_mesh`` is set and bits are
     given, attention dispatches to ``core.context_parallel
     .cp_attention`` instead — the token axis shards over
-    ``cfg.cp_axis``, per-step math follows ``cfg.attn_impl``, and the
-    combining-aware custom_vjp keeps the whole thing differentiable.
+    ``cfg.cp_axis``, per-step math follows ``resolve_attn_impl``, and
+    the combining-aware custom_vjp keeps the whole thing differentiable.
     Inputs must already be permuted to the ContextPlan layout.
     """
+    impl = resolve_attn_impl(cfg, bits, kv_override)
     x_kv = x_q if x_kv is None else x_kv
     b, tq, _ = x_q.shape
     q, k, v = attn_project_qkv(p, cfg, x_q, x_kv)
@@ -259,20 +275,20 @@ def run_attention(p: Params, cfg: ModelConfig, x_q, *, x_kv=None, q_pos=None,
                 cfg.cp_mesh, cfg.cp_axis, q, k, v, bits,
                 bits if kv_bits is None else kv_bits, q_pos,
                 q_pos if kv_pos is None else kv_pos, method=cfg.cp_method,
-                softcap=cfg.attn_softcap, window=window, impl=cfg.attn_impl)
+                softcap=cfg.attn_softcap, window=window, impl=impl)
         return out.reshape(b, tq, cfg.q_dim) @ p["wo"], (k, v)
-    elif cfg.attn_impl != "xla" and bits is not None:
+    elif impl != "xla":
         # fused Pallas BAM path: GQA folded into the kernel's index
         # maps, bitfield mask evaluated in-registers, custom_vjp with
         # (out, lse) residuals — the training hot path.
-        from repro.kernels.ops import auto_block, bam_attention
+        from repro.kernels.ops import bam_attention, flash_blocks
+        block_q, block_k = flash_blocks(tq, k.shape[1])
         with jax.named_scope("sdpa"):
             out = bam_attention(
                 q, k, v, bits, bits if kv_bits is None else kv_bits,
                 q_pos, q_pos if kv_pos is None else kv_pos,
-                softcap=cfg.attn_softcap, window=window,
-                impl=cfg.attn_impl, block_q=auto_block(tq),
-                block_k=auto_block(k.shape[1]))
+                softcap=cfg.attn_softcap, window=window, impl=impl,
+                block_q=block_q, block_k=block_k)
         return out.reshape(b, tq, cfg.q_dim) @ p["wo"], (k, v)
     with jax.named_scope("sdpa"):
         # n_rep from the actual tensor: decode caches may carry

@@ -129,10 +129,10 @@ def _block(cfg: ModelConfig, p, x, batch, layer_idx, ffn: Optional[FFN]):
     # so that stays XLA (a cp_mesh is ignored there: each device then
     # computes full attention — correct, just not context-parallel).
     kernel_bits = None
-    if ((cfg.attn_impl != "xla" or cfg.cp_mesh is not None)
-            and batch.get("bits") is not None
-            and not cfg.local_global_pattern):
-        kernel_bits = batch["bits"]
+    if not cfg.local_global_pattern and (
+            cfg.cp_mesh is not None
+            or L.resolve_attn_impl(cfg, batch.get("bits")) != "xla"):
+        kernel_bits = batch.get("bits")
 
     h = L.apply_norm(cfg, p["ln1"], x)
     with jax.named_scope("attention"):

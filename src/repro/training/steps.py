@@ -155,8 +155,9 @@ def make_cp_train_step(cfg: ModelConfig, layout, mesh,
     batch array into plan layout, then runs the ordinary loss with
     ``cfg`` rewired so attention dispatches through
     ``core.context_parallel.cp_attention`` over ``mesh``'s
-    ``axis_name`` axis (per-step math = ``cfg.attn_impl``; ``method``
-    picks allgather vs ring). Because the permutation rides every
+    ``axis_name`` axis (per-step math from
+    ``models.layers.resolve_attn_impl``; ``method`` picks allgather vs
+    ring). Because the permutation rides every
     per-token tensor and CP attention is exact, loss and grads match
     ``make_train_step`` on the unpermuted batch.
     """

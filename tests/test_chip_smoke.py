@@ -38,6 +38,7 @@ def test_four_chip_phases_reduced():
     cs = _chip_smoke()
     ok, (replay, spmd) = cs.spmd_vs_replay(reduced=True)
     assert ok and spmd["spmd"] and not replay["spmd"]
+    assert (replay["attn_impl"], spmd["attn_impl"]) == ("xla", "auto")
     ok, rows = cs.cp_vs_single(reduced=True, kernel_impl="bam_interpret")
     assert ok
     assert {(r["mode"], r["method"]) for r in rows} == {

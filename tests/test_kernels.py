@@ -342,3 +342,107 @@ def test_pad_positions_use_minus_one():
                         impl="bam_interpret", block_q=16, block_k=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The training path's tiles (ops.flash_blocks) at training head widths
+# ---------------------------------------------------------------------------
+
+def _training_row(dtype, T=480, H=4, Hkv=2, hd=128):
+    """One text-image-text row as the benchmark cells merge them, at a
+    length that is not a multiple of 128, GQA n_rep 2, hd 128."""
+    key = jax.random.PRNGKey(11)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (1, T, H, hd), dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, T, Hkv, hd), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, T, Hkv, hd), dtype)
+    w = jax.random.normal(jax.random.fold_in(key, 3), (1, T, H, hd))
+    bits_np, pos_np = bam.build_sample_bits(
+        [("text", 0, T // 4), ("mod", 1, T // 3),
+         ("text", 0, T - T // 4 - T // 3)], T)
+    return q, k, v, w, jnp.asarray(bits_np)[None], jnp.asarray(pos_np)[None]
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_training_tiles_match_oracle_and_xla(dtype):
+    """At the tiles ``flash_blocks`` picks (256, padding 480 to 512 as
+    896 pads the cells' 1600 to 1792),
+    the kernel's output and dq/dk/dv match the f32 oracle and the XLA
+    ``sdpa`` path. Float32 inputs keep float32 operands and the 2e-5 /
+    1e-4 tolerances used above. Bfloat16 inputs enter the MXU as bf16
+    with f32 accumulation, as the XLA path's do: each result lies within
+    1e-2 of the oracle's norm and within twice the XLA path's own error."""
+    from repro.kernels.ops import flash_blocks
+    from repro.models import layers as L
+    q, k, v, w, bits, pos = _training_row(dtype)
+    T, H, Hkv = q.shape[1], q.shape[2], k.shape[2]
+    bq, bk = flash_blocks(T, T)
+    assert T % bq and T % bk          # the tiles pad this row
+
+    def kernel(q, k, v):
+        return bam_attention(q, k, v, bits, bits, pos, pos,
+                             impl="bam_interpret", block_q=bq, block_k=bk)
+
+    def xla(q, k, v):
+        mask = bam.allowed_mask(bits, bits, pos, pos)[:, None]
+        n_rep = H // Hkv
+        return L.sdpa(q, L.repeat_kv(k, n_rep), L.repeat_kv(v, n_rep), mask)
+
+    def oracle(q, k, v):
+        return bam_attention_ref(q, k, v, bits, bits, pos, pos)
+
+    def fwd_bwd(f, *args):
+        out, vjp = jax.vjp(lambda *a: f(*a).astype(jnp.float32), *args)
+        return (out, *vjp(w))
+
+    got = fwd_bwd(kernel, q, k, v)
+    via_xla = fwd_bwd(xla, q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = fwd_bwd(oracle, *(x.astype(jnp.float32) for x in (q, k, v)))
+    if dtype == jnp.float32:
+        for i, (a, b, c) in enumerate(zip(got, want, via_xla)):
+            tol = 2e-5 if i == 0 else 1e-4
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=tol, rtol=tol)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       atol=tol, rtol=tol)
+        return
+    for a, b, c in zip(got, want, via_xla):
+        err, err_xla = _rel(a, b), _rel(c, b)
+        assert err < 1e-2 and err < 2 * err_xla, (err, err_xla)
+        assert _rel(a, c) < 1e-2
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("window", [0, 5])
+def test_mask_words_match_allowed_mask(seed, window):
+    """The training kernels' per-token word rewrite of the mask equals
+    ``bam.allowed_mask`` on arbitrary bitfields — random attends sets,
+    modalities beyond the attends set's reach, instances, padding,
+    positions — in both tile orientations."""
+    from repro.kernels.bam_attention import _col, _mask_words, _row, \
+        _words_mask
+    rng = np.random.default_rng(seed)
+    T = 96
+    bits = (rng.integers(0, 1 << 16, T)
+            | rng.integers(0, 20, T) << bam.MOD_SHIFT
+            | rng.integers(0, 3, T) << bam.INST_SHIFT).astype(np.uint32)
+    bits[rng.random(T) < 0.3] = bam.text_token((1, 2))
+    bits[rng.random(T) < 0.1] = 0
+    pos = rng.integers(-1, 40, T).astype(np.int32)
+    want = np.asarray(bam.allowed_mask(bits[None], bits[None], pos[None],
+                                       pos[None], window))[0]
+    q_words, k_words = _mask_words(jnp.asarray(bits)[None],
+                                   jnp.asarray(bits)[None],
+                                   jnp.asarray(pos)[None],
+                                   jnp.asarray(pos)[None], window)
+    got = _words_mask([_col(w) for w in q_words], [_row(w) for w in k_words])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    got_t = _words_mask([_row(w) for w in q_words],
+                        [_col(w) for w in k_words])
+    np.testing.assert_array_equal(np.asarray(got_t), want.T)
+    assert 0 < want.sum() < want.size

@@ -204,6 +204,77 @@ def test_attn_impl_kernel_matches_xla():
                                    atol=2e-3, rtol=2e-3)
 
 
+def _routing_cfg(**kw):
+    from repro.configs.base import ModelConfig
+    return ModelConfig(name="tiny", family="dense", num_layers=2, d_model=32,
+                       num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                       dtype="float32", remat=False,
+                       seq_shard_activations=False, **kw)
+
+
+def _spy_kernel(monkeypatch, backend):
+    """Report ``backend`` as JAX's default and record the impl of every
+    call that reaches the fused kernel path (which returns zeros)."""
+    from repro.kernels import ops
+    calls = []
+
+    def spy(q, k, v, *a, impl, **kw):
+        calls.append(impl)
+        return jnp.zeros_like(q)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(ops, "bam_attention", spy)
+    return calls
+
+
+@pytest.mark.parametrize("backend,attn_impl,with_bits,decode,expect", [
+    ("tpu", "auto", True, False, "bam_kernel"),
+    ("tpu", "auto", False, False, "xla"),
+    ("tpu", "auto", True, True, "xla"),
+    ("cpu", "auto", True, False, "xla"),
+    ("tpu", "xla", True, False, "xla"),
+    ("cpu", "bam_interpret", True, False, "bam_interpret"),
+    ("cpu", "bam_kernel", True, False, "bam_kernel"),
+])
+def test_attention_routing(monkeypatch, backend, attn_impl, with_bits,
+                           decode, expect):
+    """By default the fused BAM kernel runs on TPU when the call carries
+    BAM bits; XLA runs without bits, on the decode path and on other
+    backends; an explicit ``attn_impl`` wins."""
+    from repro.core import bam
+    from repro.models import layers as L
+    cfg = _routing_cfg(attn_impl=attn_impl)
+    calls = _spy_kernel(monkeypatch, backend)
+    T_ = 24
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, T_, cfg.d_model))
+    p = L.attn_init(jax.random.PRNGKey(1), cfg, jnp.float32)
+    pos = jnp.arange(T_, dtype=jnp.int32)[None]
+    bits = bam.causal_bits(1, T_) if with_bits else None
+    kv_override = (lambda k, v: (k, v)) if decode else None
+    assert L.resolve_attn_impl(cfg, bits, kv_override) == expect
+    L.run_attention(p, cfg, x, q_pos=pos, bits=bits, kv_override=kv_override)
+    assert calls == ([] if expect == "xla" else [expect])
+
+
+@pytest.mark.parametrize("local_global,expect", [(0, ["bam_kernel"]),
+                                                 (2, [])])
+def test_transformer_routes_bits_to_kernel_on_tpu(monkeypatch, local_global,
+                                                  expect):
+    """The transformer's layers take the kernel on TPU when the batch
+    carries bits, except under gemma2's traced local/global windows."""
+    from repro.core import bam
+    from repro.models import transformer as tf
+    cfg = _routing_cfg(local_global_pattern=local_global,
+                       sliding_window=8 if local_global else 0)
+    params = tf.init(jax.random.PRNGKey(0), cfg)
+    calls = _spy_kernel(monkeypatch, "tpu")
+    T_ = 16
+    batch = {"tokens": jnp.zeros((1, T_), jnp.int32),
+             "positions": jnp.arange(T_, dtype=jnp.int32)[None],
+             "bits": bam.causal_bits(1, T_)}
+    tf.forward(params, cfg, batch)
+    assert calls == expect
+
+
 def test_vlm_mrope_text_equals_rope():
     """M-RoPE with equal (t,h,w) ids == standard RoPE (text tokens)."""
     from repro.models.layers import apply_mrope, apply_rope

@@ -1,13 +1,17 @@
 """The Pallas kernels of the training and serving paths, compiled ahead
 of time for a described TPU v5e at LLM-S head widths (H=16, Hkv=4,
-hd=128, T=2048). Nothing runs: Mosaic compiles each kernel for a chip
-that is described, not attached, and refuses what the chip would refuse
-(block tiling, VMEM budget) — which interpret mode never does.
+hd=128, T=2048), and the training path's kernels at the tiles
+``ops.flash_blocks`` picks for the benchmark cells' attention (H=16,
+Hkv=8, hd=128; T=4096, and T=1600 padded). Nothing runs: Mosaic
+compiles each kernel for a chip that is described, not attached, and
+refuses what the chip would refuse (block tiling, VMEM budget) — which
+interpret mode never does.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU
 compiler's library, and the test workers import every test file.
 """
+import functools
 import os
 
 import numpy as np
@@ -103,6 +107,42 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compilation_cache):
     }
     fn, names = _cases()[case]
     compiled = jax.jit(fn).lower(*(shapes[n] for n in names)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem is not None and np.isfinite(mem.temp_size_in_bytes)
+
+
+CELL_ROWS = {"doc-4096": (2, 4096), "align-1600": (5, 1600)}
+CELL_HKV = 8
+
+
+@pytest.mark.parametrize("case", ["fwd_residual", "bwd_dense", "fwd_stats"])
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_training_kernel_compiles_for_v5e(case, cell, one_chip,
+                                          no_compilation_cache):
+    from repro.kernels.bam_attention import (bam_flash_attention,
+                                             bam_flash_attention_bwd)
+    from repro.kernels.ops import flash_blocks
+    b, t = CELL_ROWS[cell]
+    bq, bk = flash_blocks(t, t)
+    tq, tk = -(-t // bq) * bq, -(-t // bk) * bk
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = s((b, tq, H, HD), jnp.bfloat16), s((b, tk, CELL_HKV, HD),
+                                               jnp.bfloat16)
+    meta = (s((b, tq), jnp.uint32), s((b, tk), jnp.uint32),
+            s((b, tq), jnp.int32), s((b, tk), jnp.int32))
+    tiles = dict(block_q=bq, block_k=bk)
+    if case == "bwd_dense":
+        fn = functools.partial(bam_flash_attention_bwd, **tiles)
+        args = (q, kv, kv, q, q, s((b, H, tq), jnp.float32), *meta)
+    else:
+        fn = functools.partial(bam_flash_attention, **tiles,
+                               return_mode=case.split("_")[1])
+        args = (q, kv, kv, *meta)
+    compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem is not None and np.isfinite(mem.temp_size_in_bytes)
