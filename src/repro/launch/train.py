@@ -265,35 +265,19 @@ def _mllm_ds_factory(args, mllm):
     return ds_factory
 
 
-def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
-    """Real-model distributed training: the plan's compiled wave
-    program drives the MLLM's own stage partition (``models.stages``)
-    through the ``shard_map`` runner every step — no toy stages
-    anywhere on this path. Loss and grads are the per-microbatch sums
-    rescaled by ``1/M``, which makes them numerically comparable to
-    (and tested against) the single-process ``make_mllm_train_step``.
-    """
-    import json
-
+def spmd_parts(mllm, plan, executor):
+    """What ``--spmd`` trains with: the plan's stage runner over its
+    mesh, and the runner's value-and-grad. Returns (stage bundle, the
+    replicated sharding the stage params live in, value_and_grad). Loss
+    and grads are the per-microbatch sums rescaled by ``1/M``, which
+    makes them comparable to (and tested against) the single-process
+    ``make_mllm_train_step``."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.parallel.spmd import build_spmd_runner, mesh_from_plan
-    from repro.resilience.monitor import init_health
-
     D = int(executor["schedule"]["num_devices"])
-    if len(jax.devices()) < D:
-        msg = (f"--spmd needs {D} devices for this plan but the process "
-               f"has {len(jax.devices())}")
-        if jax.default_backend() == "cpu":     # forced host devices
-            msg += (f"; relaunch with XLA_FLAGS=--xla_force_host_"
-                    f"platform_device_count={D}")
-        raise SystemExit(msg)
     bundle = executor["stage_bundle"]
     M = int(plan.schedule.num_microbatches)
-    if args.batch % M != 0:
-        raise SystemExit(
-            f"--spmd needs --batch divisible by the plan's "
-            f"{M} microbatches, got --batch {args.batch}")
     mesh = mesh_from_plan(plan, mllm, D)
     print("pipeline ranks -> devices: " + ", ".join(
         f"{r}:{d}" for r, d in enumerate(mesh.devices.flat)))
@@ -303,16 +287,6 @@ def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
         microbatch_loss=bundle.microbatch_loss,
         program=executor["spmd_program"],
         trainable=list(bundle.trainable))
-
-    params = mllm.init(jax.random.PRNGKey(args.seed))
-    # replicated over the pipeline mesh up front, where every step's
-    # outputs land: inputs placed elsewhere would recompile step two
-    stage_params = jax.device_put(bundle.partition(params),
-                                  NamedSharding(mesh, PartitionSpec()))
-    frozen_mask = bundle.frozen_masks(stage_params)
-    ocfg = opt.AdamWConfig(lr=args.lr, warmup_steps=min(50, args.steps // 10
-                                                        or 1),
-                           total_steps=args.steps)
     scale = 1.0 / M
 
     def value_and_grad_fn(sp, batch):
@@ -326,11 +300,53 @@ def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
         loss = loss * scale
         return (loss, {"ce": loss}), grads
 
+    return bundle, NamedSharding(mesh, PartitionSpec()), value_and_grad_fn
+
+
+def _train_mllm_spmd(args, mllm, plan, executor) -> dict:
+    """Real-model distributed training: the plan's compiled wave
+    program drives the MLLM's own stage partition (``models.stages``)
+    through the ``shard_map`` runner every step (``spmd_parts``) — no
+    toy stages anywhere on this path.
+    """
+    import json
+
+    from repro.resilience.monitor import init_health
+
+    D = int(executor["schedule"]["num_devices"])
+    if len(jax.devices()) < D:
+        msg = (f"--spmd needs {D} devices for this plan but the process "
+               f"has {len(jax.devices())}")
+        if jax.default_backend() == "cpu":     # forced host devices
+            msg += (f"; relaunch with XLA_FLAGS=--xla_force_host_"
+                    f"platform_device_count={D}")
+        raise SystemExit(msg)
+    M = int(plan.schedule.num_microbatches)
+    if args.batch % M != 0:
+        raise SystemExit(
+            f"--spmd needs --batch divisible by the plan's "
+            f"{M} microbatches, got --batch {args.batch}")
+    bundle, replicated, value_and_grad_fn = spmd_parts(mllm, plan, executor)
+
+    key = jax.random.PRNGKey(args.seed)
+    # the stage list straight from the seed, replicated over the
+    # pipeline mesh, where every step's outputs land (inputs placed
+    # elsewhere would recompile step two); the whole tree never sits on
+    # one device first
+    stage_params = jax.jit(lambda k: bundle.partition(mllm.init(k)),
+                           out_shardings=replicated)(key)
+    frozen_mask = bundle.frozen_masks(stage_params)
+    ocfg = opt.AdamWConfig(lr=args.lr, warmup_steps=min(50, args.steps // 10
+                                                        or 1),
+                           total_steps=args.steps)
+
     def convert_checkpoint(manager, peek):
         # replay-mode checkpoint -> stage list: load under the
         # whole-model layout, then partition per this plan's stages
+        params = jax.eval_shape(mllm.init, key)
         like = {"params": params,
-                "opt": opt.init(ocfg, params, mllm.frozen_mask(params)),
+                "opt": jax.eval_shape(lambda p: opt.init(
+                    ocfg, p, mllm.frozen_mask(p)), params),
                 "health": init_health()}
         tree, step, src = manager.restore(like)
         return (bundle.partition(tree["params"]),

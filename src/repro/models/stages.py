@@ -334,18 +334,21 @@ def build_mllm_stages(mllm, executor: Dict[str, Any], *,
                     blk = jax.checkpoint(blk)
                 return blk(h), None
 
-            h, _ = lax.scan(body, h, layers)
+            with jax.named_scope("encoder"):
+                h, _ = lax.scan(body, h, layers)
+                if sp.last:
+                    fl = _stop(lp["final_ln"]) if enc.frozen_module \
+                        else lp["final_ln"]
+                    h = L.apply_norm(cfg, fl, h)
             if not sp.last:
                 return jnp.zeros_like(x).at[:, off:off + n, :dm].set(
                     h.astype(x.dtype))
-            fl = _stop(lp["final_ln"]) if enc.frozen_module \
-                else lp["final_ln"]
-            h = L.apply_norm(cfg, fl, h)
             proj = _stop(lp["projector"]) if enc.frozen_projector \
                 else lp["projector"]
-            out = h @ proj["w1"]
-            if "w2" in proj:
-                out = jax.nn.gelu(out) @ proj["w2"]
+            with jax.named_scope("projector"):
+                out = h @ proj["w1"]
+                if "w2" in proj:
+                    out = jax.nn.gelu(out) @ proj["w2"]
             return jnp.zeros_like(x).at[:, off:off + n, :d_llm].set(
                 out.astype(x.dtype))
         return fn
@@ -357,6 +360,16 @@ def build_mllm_stages(mllm, executor: Dict[str, Any], *,
         def fn(lp, x, mb):
             if mllm.frozen_llm:
                 lp = _stop(lp)
+            h = hidden(lp, x, mb)
+            if not sp.last:
+                return jnp.zeros_like(x).at[:, :, :cfg.d_model].set(
+                    h.astype(x.dtype))
+            return head(lp, x, mb, h)
+
+        @jax.named_scope("llm")
+        def hidden(lp, x, mb):
+            """The stage's share of ``transformer.hidden``: the embedding
+            on the first stage, its layers, the final norm on the last."""
             B = x.shape[0]
             Tc = x.shape[1]
             batch = {
@@ -388,10 +401,14 @@ def build_mllm_stages(mllm, executor: Dict[str, Any], *,
 
             h, _ = lax.scan(body, h,
                             (lp["layers"], jnp.arange(lo, hi)))
-            if not sp.last:
-                return jnp.zeros_like(x).at[:, :, :cfg.d_model].set(
-                    h.astype(x.dtype))
-            h = L.apply_norm(cfg, lp["final_ln"], h)
+            if sp.last:
+                h = L.apply_norm(cfg, lp["final_ln"], h)
+            return h
+
+        @jax.named_scope("lm_head")
+        def head(lp, x, mb, h):
+            """The head and each text position's negative
+            log-likelihood, in carrier channel 0."""
             w = lp["embed"].T if cfg.tie_embeddings else lp["unembed"]
             logits = h @ w
             if cfg.final_softcap:

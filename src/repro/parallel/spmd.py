@@ -34,8 +34,12 @@ instruction table dispatching one ``lax.switch`` over *distinct*
 distinct instructions rather than timeline length (the fully-unrolled
 ``dispatch="switch"`` baseline is kept for comparison). Stage fns may
 be real-model per-stage callables (``models.stages.build_mllm_stages``
-— heterogeneous params travel as a replicated list with psum-reduced
-grads) or a single homogeneous callable. Loss and outputs are
+— heterogeneous params travel as a replicated list; only the leaves a
+trainable stage's cotangent reaches hold, accumulate and psum-reduce a
+weight gradient, the rest come back as zeros) or a single homogeneous
+callable. Each work item runs under ``jax.named_scope`` ``stage{s}`` >
+``F``/``B``/``W``, each wave boundary's comm rounds under ``handoff``
+and the closing reductions under ``pipeline_reduce``. Loss and outputs are
 ``psum``-reduced over the pipeline axis; per-item occupancy is written
 into a trace buffer and reassembled host-side into the same
 ``activation_trace`` format ``execute_schedule`` returns, so
@@ -59,6 +63,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.extend.core import Literal
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.schedule.graph import PipelineGraph
@@ -335,6 +340,43 @@ def _rolled_tables(prog: SPMDProgram):
     return keys, instr, m_tab, item_tab, R, comm
 
 
+def _grad_support(fn: Callable, lp: Any, x: Any, mb: Any) -> List[int]:
+    """Indices of the leaves of ``lp`` (a stage's params) whose weight
+    gradient can be nonzero. A VJP is linear in its cotangent, so a
+    leaf whose gradient does not read the cotangent is identically zero:
+    a subtree the stage fn holds under ``stop_gradient`` (a frozen
+    encoder beside its trainable projector). Read off the VJP's jaxpr
+    at the given shapes; nothing runs."""
+    leaves, tdef = jax.tree.flatten(lp)
+
+    def grads(leaves, x, mb, g):
+        _, vjp = jax.vjp(lambda *ls: fn(tdef.unflatten(ls), x, mb),
+                         *leaves)
+        return vjp(g)
+
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    y = jax.eval_shape(fn, lp, x, mb)
+    jaxpr = jax.make_jaxpr(grads)(*jax.tree.map(
+        shape, (leaves, x, mb, y))).jaxpr
+    live = set(jaxpr.invars[-len(jax.tree.leaves(y)):])
+    for eqn in jaxpr.eqns:
+        if any(v in live for v in eqn.invars
+               if not isinstance(v, Literal)):
+            live.update(eqn.outvars)
+    return [i for i, v in enumerate(jaxpr.outvars)
+            if not isinstance(v, Literal) and v in live]
+
+
+def _with_zeros(params: Any, held: Sequence[int], grads: Sequence[Any]):
+    """``params``' tree with ``grads`` at the leaves ``held`` indexes
+    and zeros everywhere else."""
+    leaves, tdef = jax.tree.flatten(params)
+    out = [jnp.zeros(a.shape, a.dtype) for a in leaves]
+    for i, g in zip(held, grads):
+        out[i] = g
+    return tdef.unflatten(out)
+
+
 def build_spmd_runner(stage_fn, graph: PipelineGraph,
                       sim: Dict[str, Any], *,
                       mesh: Optional[Mesh] = None,
@@ -356,7 +398,8 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
     ``fn(lp, x, microbatch)`` (``models.stages.StageBundle.stage_fns``).
     ``stage_params`` may be stage-stacked (homogeneous stages, sharded
     ``[D, L, ...]`` per device) or a list of per-stage trees
-    (heterogeneous real-model stages; replicated, grads psum-reduced —
+    (heterogeneous real-model stages; replicated, the grads of the
+    leaves that can have one psum-reduced, zeros elsewhere —
     ``param_grads`` then comes back as a matching list). ``trainable``
     has ``execute_schedule``'s semantics (stages that must produce
     weight grads even with ``bwd_w == 0``).
@@ -414,6 +457,14 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
         xshape, xdtype = mbs.shape[1:], mbs.dtype
         loss_dtype = jax.eval_shape(
             loss_fn, jax.ShapeDtypeStruct(xshape, xdtype)).dtype
+        if hetero:
+            # the leaves that take weight grads: of trainable stages only,
+            # and only those the cotangent reaches, so a frozen stage (or
+            # a frozen tower beside its projector) holds, accumulates and
+            # reduces no gradient buffer at all
+            xs = jax.ShapeDtypeStruct(xshape, xdtype)
+            held = [_grad_support(fns[s], local_params[s], xs, xs)
+                    if trainable[s] else [] for s in range(S)]
 
         def body(local_params, mbs):
             if hetero:
@@ -422,8 +473,9 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 lp = jax.tree.map(lambda a: a[0], local_params)  # [L,...]
             idx = lax.axis_index(axis_name)
             if hetero:
-                zgrads = tuple(jax.tree.map(jnp.zeros_like, p)
-                               for p in params_t)
+                zgrads = tuple(
+                    tuple(jnp.zeros_like(jax.tree.leaves(params_t[s])[i])
+                          for i in held[s]) for s in range(S))
             else:
                 zgrads = jax.tree.map(jnp.zeros_like, lp)
             state = {
@@ -450,12 +502,28 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
             def add_grads(st, s, c, gp):
                 if hetero:
                     gl = list(st["grads"])
-                    gl[s] = jax.tree.map(jnp.add, gl[s], gp)
+                    gl[s] = tuple(map(jnp.add, gl[s], gp))
                     st["grads"] = tuple(gl)
                 else:
                     st["grads"] = jax.tree.map(
                         lambda G, dG: G.at[c].add(dG), st["grads"], gp)
                 return st
+
+            def weight_grads(s, lpc, x, mb, g):
+                """The stage's weight VJP: over the held leaves only
+                (hetero), the rest closed over as constants."""
+                if not hetero:
+                    _, vjp_p = jax.vjp(lambda pw: fns[s](pw, x, mb), lpc)
+                    return vjp_p(g)[0]
+                leaves, tdef = jax.tree.flatten(lpc)
+
+                def f(*hl):
+                    ls = list(leaves)
+                    for i, h in zip(held[s], hl):
+                        ls[i] = h
+                    return fns[s](tdef.unflatten(ls), x, mb)
+                _, vjp_p = jax.vjp(f, *[leaves[i] for i in held[s]])
+                return vjp_p(g)
 
             def make_branch(kind, s):
                 # device/chunk are static per stage; the microbatch and
@@ -464,6 +532,8 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 stg = graph.stages[s]
                 prs, sucs = preds[s], succs[s]
 
+                @jax.named_scope(f"stage{s}")
+                @jax.named_scope(kind)
                 def br(st, m, i):
                     st = dict(st)
                     if hetero:
@@ -512,19 +582,15 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                                 st["wused"] = st["wused"].at[
                                     c, m].set(True)
                             else:                # glued: weight grads now
-                                _, vjp_p = jax.vjp(
-                                    lambda pw: fns[s](pw, x, mb), lpc)
-                                (gp,) = vjp_p(g)
-                                st = add_grads(st, s, c, gp)
+                                st = add_grads(st, s, c, weight_grads(
+                                    s, lpc, x, mb, g))
                     else:                        # W
                         x = st["wx"][c, m]
                         g = st["wg"][c, m]
                         st["wused"] = st["wused"].at[c, m].set(False)
                         if trainable[s]:
-                            _, vjp_p = jax.vjp(
-                                lambda pw: fns[s](pw, x, mb), lpc)
-                            (gp,) = vjp_p(g)
-                            st = add_grads(st, s, c, gp)
+                            st = add_grads(st, s, c, weight_grads(
+                                s, lpc, x, mb, g))
                     st["occ"] = st["occ"].at[i].set(
                         jnp.sum(st["used"]).astype(jnp.int32))
                     if has_w:
@@ -546,6 +612,7 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                     c_a, m2_a = jnp.asarray(c_t), jnp.asarray(m2_t)
                     isb_a = jnp.asarray(isb_t)
 
+                @jax.named_scope("handoff")
                 def comm_rounds(w, st):
                     def round_body(r, st):
                         st = dict(st)
@@ -585,6 +652,26 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                     return lambda st, br=br, m=m, i=i: br(
                         st, jnp.int32(m), jnp.int32(i))
 
+                @jax.named_scope("handoff")
+                def handoff(state, rnd):
+                    state = dict(state)
+                    buf = state["fy"] if rnd.kind == "fwd" else state["bg"]
+                    recv = lax.ppermute(buf, axis_name, rnd.pairs)
+                    on = [False] * D
+                    cs = [0] * D
+                    ms = [0] * D
+                    for t in rnd.transfers:
+                        on[t.dst_dev] = True
+                        cs[t.dst_dev] = chunk_of[t.dst_stage]
+                        ms[t.dst_dev] = t.microbatch
+                    c = jnp.asarray(cs)[idx]
+                    m = jnp.asarray(ms)[idx]
+                    delta = jnp.where(jnp.asarray(on)[idx], recv,
+                                      jnp.zeros_like(recv))
+                    key = "inbox" if rnd.kind == "fwd" else "cot"
+                    state[key] = state[key].at[c, m].add(delta)
+                    return state
+
                 for wave in prog.waves:
                     branches = [static_branch(d, wave.compute[d])
                                 if d in wave.compute
@@ -592,49 +679,39 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                                 for d in range(D)]
                     state = lax.switch(idx, branches, state)
                     for rnd in wave.rounds:
-                        buf = state["fy"] if rnd.kind == "fwd" \
-                            else state["bg"]
-                        recv = lax.ppermute(buf, axis_name, rnd.pairs)
-                        on = [False] * D
-                        cs = [0] * D
-                        ms = [0] * D
-                        for t in rnd.transfers:
-                            on[t.dst_dev] = True
-                            cs[t.dst_dev] = chunk_of[t.dst_stage]
-                            ms[t.dst_dev] = t.microbatch
-                        c = jnp.asarray(cs)[idx]
-                        m = jnp.asarray(ms)[idx]
-                        delta = jnp.where(jnp.asarray(on)[idx], recv,
-                                          jnp.zeros_like(recv))
-                        key = "inbox" if rnd.kind == "fwd" else "cot"
-                        state[key] = state[key].at[c, m].add(delta)
+                        state = handoff(state, rnd)
 
-            outputs = lax.psum(state["out"], axis_name)
-            loss = lax.psum(state["loss"], axis_name)
-            if hetero:
-                grads = jax.tree.map(
-                    lambda a: lax.psum(a, axis_name), state["grads"])
-            else:
-                grads = jax.tree.map(lambda a: a[None], state["grads"])
+            with jax.named_scope("pipeline_reduce"):
+                outputs = lax.psum(state["out"], axis_name)
+                loss = lax.psum(state["loss"], axis_name)
+                if hetero:
+                    grads = lax.psum(state["grads"], axis_name)
+                else:
+                    grads = jax.tree.map(lambda a: a[None], state["grads"])
             return (outputs, loss, grads,
                     state["occ"][None], state["wocc"][None])
 
         if hetero:
             spec_p = jax.tree.map(
                 lambda a: P(*([None] * a.ndim)), local_params)
-            grads_spec = spec_p
+            grads_spec = tuple(tuple(P() for _ in h) for h in held)
         else:
             spec_p = jax.tree.map(
                 lambda a: P(axis_name, *([None] * (a.ndim - 1))),
                 local_params)
             grads_spec = spec_p
-        return jax.shard_map(
+        outputs, loss, grads, occ, wocc = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec_p, P(*([None] * mbs.ndim))),
             out_specs=(P(*([None] * mbs.ndim)), P(), grads_spec,
                        P(axis_name, None), P(axis_name, None)),
             check_vma=False,
         )(local_params, mbs)
+        if hetero:
+            # every stage's full tree again: zeros where no grad is held
+            grads = tuple(_with_zeros(p, h, g)
+                          for p, h, g in zip(local_params, held, grads))
+        return outputs, loss, grads, occ, wocc
 
     core_fn = jax.jit(core, static_argnames=("hetero",)) if jit else core
 

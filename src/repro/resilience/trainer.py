@@ -118,8 +118,12 @@ class ResilientTrainer:
         self.step_fn = step_fn
         # all training state is committed to where the params are: the
         # step's outputs land there, and inputs placed (or left
-        # uncommitted) anywhere else would make step two compile again
+        # uncommitted) anywhere else would make step two compile again;
+        # each step's batch and controls go there too, so that the step
+        # sees one placement of all its arguments (on a pipeline mesh,
+        # replicated over it, not on the default device alone)
         where = getattr(jax.tree.leaves(params)[0], "sharding", None)
+        self._where = where
         self.params = jax.device_put(params, where)
         self.opt_state = jax.device_put(opt_state, where)
         self.health = jax.device_put(init_health(), where)
@@ -201,7 +205,7 @@ class ResilientTrainer:
         c["max_grad_norm"] = jnp.float32(self.monitor.cfg.max_grad_norm)
         c["clip_scale"] = jnp.float32(self.clip_scale)
         c["inject_nan"] = jnp.float32(1.0 if inject_nan else 0.0)
-        return c
+        return jax.device_put(c, self._where)
 
     def _rollback(self, step: int, reason: str) -> None:
         self._attempts += 1
@@ -242,7 +246,7 @@ class ResilientTrainer:
                     continue
 
                 with jax.profiler.TraceAnnotation("trainer.batch"):
-                    batch = self.stream.next()
+                    batch = jax.device_put(self.stream.next(), self._where)
                 t0 = time.perf_counter()
                 with jax.profiler.TraceAnnotation("trainer.dispatch"):
                     self.params, self.opt_state, self.health, bundle = \

@@ -350,7 +350,7 @@ def test_pad_positions_use_minus_one():
 
 def _training_row(dtype, T=480, H=4, Hkv=2, hd=128):
     """One text-image-text row as the benchmark cells merge them, at a
-    length that is not a multiple of 128, GQA n_rep 2, hd 128."""
+    length that is not a multiple of 128, GQA n_rep H / Hkv, hd 128."""
     key = jax.random.PRNGKey(11)
     q = jax.random.normal(jax.random.fold_in(key, 0), (1, T, H, hd), dtype)
     k = jax.random.normal(jax.random.fold_in(key, 1), (1, T, Hkv, hd), dtype)
@@ -376,9 +376,20 @@ def test_training_tiles_match_oracle_and_xla(dtype):
     1e-4 tolerances used above. Bfloat16 inputs enter the MXU as bf16
     with f32 accumulation, as the XLA path's do: each result lies within
     1e-2 of the oracle's norm and within twice the XLA path's own error."""
+    _check_training_tiles(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_training_tiles_match_oracle_at_gqa_7(dtype):
+    """The same at Qwen2-VL-7B's GQA: 7 query heads to each KV head
+    (28 / 4 as published), which the dK/dV kernel folds in VMEM."""
+    _check_training_tiles(dtype, H=7, Hkv=1)
+
+
+def _check_training_tiles(dtype, **heads):
     from repro.kernels.ops import flash_blocks
     from repro.models import layers as L
-    q, k, v, w, bits, pos = _training_row(dtype)
+    q, k, v, w, bits, pos = _training_row(dtype, **heads)
     T, H, Hkv = q.shape[1], q.shape[2], k.shape[2]
     bq, bk = flash_blocks(T, T)
     assert T % bq and T % bk          # the tiles pad this row

@@ -37,14 +37,29 @@ LAUNCH_ARGS = ["--mllm", "vlm", "--reduced", "--steps", "4",
                "--log-every", "0"]
 
 
+#: the cases the stage partition is checked at: the reduced paper LLM;
+#: Qwen2-VL's attention shape (q/k/v biases, 7 query heads to each KV
+#: head; the reduced LLM's head is already untied, with no QK-norm); and
+#: the ft1 fine-tune, whose trainable LLM stages take weight grads
+CASES = {"paper": ({}, False),
+         "qwen2vl": ({"num_heads": 7, "num_kv_heads": 1, "head_dim": 32,
+                      "qkv_bias": True}, False),
+         "ft1": ({}, True)}
+
+
 @functools.lru_cache(maxsize=None)
-def tiny_case():
+def tiny_case(case="paper"):
     """A real (reduced) VLM + a searched plan + its SPMD executor
     contract — the fixture every test here partitions. Cached per
     process: the plan search and stage build are deterministic."""
     from repro.models.mllm import build_paper_mllm
     from repro.parallel import ClusterSpec, WorkloadShape, parallelize
     mllm = build_paper_mllm("vlm", reduced=True, text_len=TEXT)
+    shape, train_llm = CASES[case]
+    mllm.llm_cfg = mllm.llm_cfg.replace(**shape)
+    assert not mllm.llm_cfg.tie_embeddings and not mllm.llm_cfg.use_qk_norm
+    if train_llm:
+        mllm.freeze("llm", module=False)
     plan = parallelize(
         mllm, ClusterSpec(num_devices=3),
         WorkloadShape(text_len=TEXT, num_microbatches=M,
@@ -154,13 +169,14 @@ def test_encode_microbatches_rejects_indivisible_batch():
 # distributed runner + train step (multi-device)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("case", sorted(CASES))
 @subprocess_test(3)
-def test_spmd_runner_trains_real_mllm():
+def test_spmd_runner_trains_real_mllm(case):
     """Tentpole oracle, distributed half: the shard_map runner on the
     real stage partition matches the single-process trainer, and one
     ``make_spmd_train_step`` update moves ONLY the trainable params."""
     from repro.parallel.spmd import build_spmd_runner, mesh_from_plan
-    mllm, plan, ex = tiny_case()
+    mllm, plan, ex = tiny_case(case)
     bundle = ex["stage_bundle"]
     D = int(ex["schedule"]["num_devices"])
     mesh = mesh_from_plan(plan, mllm, D)

@@ -2,7 +2,8 @@
 of time for a described TPU v5e at LLM-S head widths (H=16, Hkv=4,
 hd=128, T=2048), and the training path's kernels at the tiles
 ``ops.flash_blocks`` picks for the benchmark cells' attention (H=16,
-Hkv=8, hd=128; T=4096, and T=1600 padded). Nothing runs: Mosaic
+Hkv=8, hd=128; T=4096, and T=1600 padded; and the pipeline cell's
+H=28, Hkv=4 at T=1600). Nothing runs: Mosaic
 compiles each kernel for a chip that is described, not attached, and
 refuses what the chip would refuse (block tiling, VMEM budget) — which
 interpret mode never does.
@@ -112,8 +113,11 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compilation_cache):
     assert mem is not None and np.isfinite(mem.temp_size_in_bytes)
 
 
-CELL_ROWS = {"doc-4096": (2, 4096), "align-1600": (5, 1600)}
-CELL_HKV = 8
+#: rows, merged length, query heads and KV heads of each cell's attention:
+#: Qwen3-1.7B's 16 / 8 in the one-chip cells, Qwen2-VL-7B's 28 / 4 (GQA
+#: 7:1) in the pipeline cell, one microbatch row a call
+CELL_ROWS = {"doc-4096": (2, 4096, 16, 8), "align-1600": (5, 1600, 16, 8),
+             "pp4-align-1600": (1, 1600, 28, 4)}
 
 
 @pytest.mark.parametrize("case", ["fwd_residual", "bwd_dense", "fwd_stats"])
@@ -123,21 +127,21 @@ def test_training_kernel_compiles_for_v5e(case, cell, one_chip,
     from repro.kernels.bam_attention import (bam_flash_attention,
                                              bam_flash_attention_bwd)
     from repro.kernels.ops import flash_blocks
-    b, t = CELL_ROWS[cell]
+    b, t, h, hkv = CELL_ROWS[cell]
     bq, bk = flash_blocks(t, t)
     tq, tk = -(-t // bq) * bq, -(-t // bk) * bk
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    q, kv = s((b, tq, H, HD), jnp.bfloat16), s((b, tk, CELL_HKV, HD),
+    q, kv = s((b, tq, h, HD), jnp.bfloat16), s((b, tk, hkv, HD),
                                                jnp.bfloat16)
     meta = (s((b, tq), jnp.uint32), s((b, tk), jnp.uint32),
             s((b, tq), jnp.int32), s((b, tk), jnp.int32))
     tiles = dict(block_q=bq, block_k=bk)
     if case == "bwd_dense":
         fn = functools.partial(bam_flash_attention_bwd, **tiles)
-        args = (q, kv, kv, q, q, s((b, H, tq), jnp.float32), *meta)
+        args = (q, kv, kv, q, q, s((b, h, tq), jnp.float32), *meta)
     else:
         fn = functools.partial(bam_flash_attention, **tiles,
                                return_mode=case.split("_")[1])

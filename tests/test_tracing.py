@@ -187,3 +187,103 @@ def test_kernel_attention_runs_under_sdpa():
     names = [[_strip(c) for c in n.split("/")] for n in
              _op_names("bam_interpret")]
     assert any("llm" in n and "sdpa" in n for n in names)
+
+
+# ---------------------------------------------------------------------------
+# The SPMD pipeline step: stage, handoff and reduction scopes
+# ---------------------------------------------------------------------------
+
+#: the pipeline runner's own scopes, and the model scopes its stage fns
+#: carry as the one-chip step does
+SPMD_SCOPES = ("handoff", "pipeline_reduce", "encoder", "projector", "llm",
+               "lm_head", "attention", "sdpa", "mlp")
+
+_SPMD_CHILD = r'''
+import json
+import re
+
+import numpy as np
+
+import jax
+
+from repro.data.synthetic import MultimodalDataset
+from repro.launch.train import spmd_parts
+from repro.models.mllm import build_paper_mllm
+from repro.optim import optimizer as opt
+from repro.parallel import ClusterSpec, WorkloadShape, parallelize
+from repro.resilience import (default_controls, init_health,
+                              make_resilient_train_step)
+
+mllm = build_paper_mllm("vlm", reduced=True, text_len=16)
+plan = parallelize(mllm, ClusterSpec(num_devices=3),
+                   WorkloadShape(text_len=16, num_microbatches=2,
+                                 microbatch_size=1, block_size=8))
+ex = plan.apply(mllm, text_len=16, mode="spmd")
+bundle, rep, vgf = spmd_parts(mllm, plan, ex)
+params = jax.jit(lambda k: bundle.partition(mllm.init(k)),
+                 out_shardings=rep)(jax.random.PRNGKey(0))
+mask = bundle.frozen_masks(params)
+ocfg = opt.AdamWConfig()
+step = jax.jit(make_resilient_train_step(None, ocfg, mask,
+                                         value_and_grad_fn=vgf))
+batch = next(iter(MultimodalDataset(
+    vocab_size=mllm.llm_cfg.vocab_size, text_len=16, batch_size=2,
+    encoder_dims={n: e.cfg.d_model for n, e in mllm.encoders.items()},
+    encoder_tokens={n: e.num_tokens for n, e in mllm.encoders.items()},
+    modality_ids={n: e.modality_id for n, e in mllm.encoders.items()},
+    seed=0)))
+text = step.lower(params, opt.init(ocfg, params, mask), init_health(),
+                  batch, default_controls()).compile().as_text()
+names = sorted({n.split(";")[0]
+                for n in re.findall(r'op_name="([^"]*)"', text)})
+print("SPMD " + json.dumps({"stages": len(bundle.specs), "names": names}))
+'''
+
+
+@pytest.fixture(scope="module")
+def spmd_step():
+    """The stage count and every ``op_name`` of the compiled guarded
+    SPMD step of a tiny VLM on three forced host devices."""
+    import json
+
+    from .helpers import run_in_subprocess
+    out = run_in_subprocess(_SPMD_CHILD, 3)
+    line = next(s for s in out.splitlines() if s.startswith("SPMD "))
+    got = json.loads(line[len("SPMD "):])
+    return got["stages"], got["names"]
+
+
+def _scoped(names, scope):
+    return [n for n in names if scope in map(_strip, n.split("/"))]
+
+
+def test_spmd_step_carries_every_stage_scope(spmd_step):
+    """Each stage's work sits under ``stage{s}``, with a child scope for
+    the kind of work: ``F`` everywhere, ``B`` on the stages that
+    propagate or take gradients."""
+    stages, names = spmd_step
+    assert stages >= 3
+    for s in range(stages):
+        chains = [[_strip(c) for c in n.split("/")]
+                  for n in _scoped(names, f"stage{s}")]
+        assert chains, f"stage{s}"
+        assert any(c[c.index(f"stage{s}") + 1] == "F" for c in chains
+                   if c.index(f"stage{s}") + 1 < len(c)), f"stage{s}/F"
+    kids = {c[c.index(s) + 1] for n in names
+            for c in [[_strip(x) for x in n.split("/")]]
+            for s in c if re.fullmatch(r"stage\d+", s) and
+            c.index(s) + 1 < len(c)}
+    assert {"F", "B"} <= kids
+
+
+@pytest.mark.parametrize("scope", SPMD_SCOPES)
+def test_spmd_step_carries_scope(spmd_step, scope):
+    assert _scoped(spmd_step[1], scope), scope
+
+
+@pytest.mark.parametrize("scope", ["llm", "lm_head"])
+def test_spmd_backward_carries_scope_under_transpose(spmd_step, scope):
+    """The B items' input and weight VJPs name the model scopes inside
+    ``transpose(jvp(...))``, as the one-chip step's backward does."""
+    assert any(any(c.startswith("transpose(jvp(") for c in n.split("/"))
+               for n in _scoped(spmd_step[1], scope)), scope
