@@ -28,16 +28,26 @@ triggers the ``on_device_loss`` hook — ``launch/train`` re-runs
 ``parallelize()`` over the shrunken ``ClusterSpec`` there — then
 resumes from the last checkpoint (device state is gone by definition).
 
+The loop looks one batch ahead: after it dispatches step n it draws and
+places batch n+1, and only then reads step n's health bundle, so the
+host's draw runs while the device computes. The batch drawn ahead waits
+on the trainer, across ``run`` calls, for the next pass; batch k still
+trains step k. A restore, an adoption or a device loss drops it, and the
+next pass draws again from the stream's restored position. Checkpoints
+record the batches consumed (``cursor``), which is one less than the
+stream's own cursor while a batch waits.
+
 The loop writes host spans into the profiler's trace
 (``jax.profiler.TraceAnnotation``; about a microsecond each when no trace
 is being taken): ``trainer.step`` around each pass of the loop (a
 ``StepTraceAnnotation``, so profilers show a step view), and inside it
-``trainer.batch`` (the stream's ``next``), ``trainer.dispatch`` (the
-step's controls and its call, which enqueues the work),
-``trainer.health_read`` (the bundle's host read, where the host waits for
-the device) and ``trainer.checkpoint`` / ``trainer.restore``. Each span's
-count is the count of what it wraps: batches drawn, dispatches, host
-syncs, saves and restores.
+``trainer.batch`` (the stream's ``next`` and the batch's placement: the
+next step's batch after the dispatch, and this step's before it where
+none waits), ``trainer.dispatch`` (the step's controls and its call,
+which enqueues the work), ``trainer.health_read`` (the bundle's host
+read, where the host waits for the device) and ``trainer.checkpoint`` /
+``trainer.restore``. Each span's count is the count of what it wraps:
+batches drawn, dispatches, host syncs, saves and restores.
 """
 from __future__ import annotations
 
@@ -138,12 +148,19 @@ class ResilientTrainer:
         self.log_every = log_every
         self.step = 0
         self.losses: Dict[int, float] = {}
-        #: wall seconds of every step call, dispatch to host sync (the
-        #: first includes compilation)
+        #: wall seconds of every step call, dispatch to host sync, with
+        #: the next batch's draw in between (the first includes
+        #: compilation)
         self.step_seconds: List[float] = []
         self.clip_scale = 1.0
         self._attempts = 0
         self._ok_streak = 0
+        #: the next step's batch, drawn and placed while the device ran
+        #: the previous step (None: the next pass draws in series)
+        self._ahead = None
+        #: steps dispatched on a batch drawn ahead / drawn in series
+        self.batches_ahead = 0
+        self.batches_in_series = 0
         if resume:
             if manager is None:
                 raise ValueError("resume=True needs a CheckpointManager")
@@ -158,17 +175,23 @@ class ResilientTrainer:
         return {"params": self.params, "opt": self.opt_state,
                 "health": self.health}
 
+    @property
+    def cursor(self) -> int:
+        """Batches consumed: the stream's cursor less the batch drawn
+        ahead, if one waits."""
+        return self.stream.cursor - (self._ahead is not None)
+
     def save_checkpoint(self, on_entry=None) -> Optional[str]:
         if self.manager is None:
             return None
         with jax.profiler.TraceAnnotation("trainer.checkpoint"):
             meta = {**self.meta, "step": self.step,
-                    "cursor": self.stream.cursor,
+                    "cursor": self.cursor,
                     "clip_scale": self.clip_scale}
             path = self.manager.save(self.step, self._state_tree(),
                                      meta=meta, on_entry=on_entry)
             self.monitor.log.emit("checkpoint", self.step, dir=path,
-                                  cursor=self.stream.cursor)
+                                  cursor=self.cursor)
         return path
 
     def adopt_state(self, params, opt_state, health=None, *,
@@ -184,6 +207,7 @@ class ResilientTrainer:
         self.opt_state = opt_state
         self.health = health if health is not None else init_health()
         self.step = int(step)
+        self._ahead = None
         self.stream.seek(int(cursor if cursor is not None else step))
         self.monitor.log.emit("adopt", self.step,
                               cursor=self.stream.cursor)
@@ -194,6 +218,7 @@ class ResilientTrainer:
             self.params, self.opt_state = tree["params"], tree["opt"]
             self.health = tree["health"]
             self.step = int(meta.get("step", step))
+            self._ahead = None
             self.stream.seek(int(meta.get("cursor", self.step)))
             self.monitor.log.emit("restore", self.step, why=why,
                                   cursor=self.stream.cursor)
@@ -206,6 +231,11 @@ class ResilientTrainer:
         c["clip_scale"] = jnp.float32(self.clip_scale)
         c["inject_nan"] = jnp.float32(1.0 if inject_nan else 0.0)
         return jax.device_put(c, self._where)
+
+    def _draw(self):
+        """The stream's next batch, placed where the params live."""
+        with jax.profiler.TraceAnnotation("trainer.batch"):
+            return jax.device_put(self.stream.next(), self._where)
 
     def _rollback(self, step: int, reason: str) -> None:
         self._attempts += 1
@@ -228,7 +258,8 @@ class ResilientTrainer:
 
     def run(self, num_steps: int) -> Dict[str, Any]:
         """Train until ``self.step == num_steps``; returns a summary
-        (losses by step, verdict counters, fired faults)."""
+        (losses by step, verdict counters, fired faults). The batch
+        drawn during the last step waits for the next call."""
         while self.step < num_steps:
             with jax.profiler.StepTraceAnnotation(
                     "trainer.step", step_num=self.step):
@@ -243,16 +274,30 @@ class ResilientTrainer:
                     if self.manager is not None and \
                             self.manager.latest() is not None:
                         self._restore("device-loss")
+                    elif self._ahead is not None:
+                        # placed for the devices just lost: draw it again
+                        self._ahead = None
+                        self.stream.seek(self.stream.cursor - 1)
                     continue
 
-                with jax.profiler.TraceAnnotation("trainer.batch"):
-                    batch = jax.device_put(self.stream.next(), self._where)
+                batch, self._ahead = self._ahead, None
+                if batch is None:
+                    batch = self._draw()
+                    self.batches_in_series += 1
+                else:
+                    self.batches_ahead += 1
                 t0 = time.perf_counter()
                 with jax.profiler.TraceAnnotation("trainer.dispatch"):
                     self.params, self.opt_state, self.health, bundle = \
                         self.step_fn(self.params, self.opt_state,
                                      self.health, batch, self._controls(
                                          self.injector.nan_at(step)))
+                # the next step's batch, while the device runs this one;
+                # where the stream ends, the next pass raises as it would
+                try:
+                    self._ahead = self._draw()
+                except StopIteration:
+                    pass
                 with jax.profiler.TraceAnnotation("trainer.health_read"):
                     b = bundle_dict(bundle)      # the step's one host sync
                 self.step_seconds.append(time.perf_counter() - t0)
@@ -297,4 +342,6 @@ class ResilientTrainer:
                              for f in self.injector.fired],
             "clip_scale": self.clip_scale,
             "step_seconds": list(self.step_seconds),
+            "batches_ahead": self.batches_ahead,
+            "batches_in_series": self.batches_in_series,
         }

@@ -1,6 +1,7 @@
 """Fault-tolerance runtime tests (repro.resilience): the in-jit health
 gate, the host verdict classifier, atomic resumable checkpoints, the
-rollback-and-retry loop, and the deterministic fault harness — up to
+rollback-and-retry loop and its one-batch lookahead (batch k+1 drawn
+while step k runs), and the deterministic fault harness — up to
 the two acceptance properties: crash-at-step-k + resume reproduces an
 uninterrupted run's losses bit-exactly, and an injected NaN-grad step
 is detected, rolled back, and training re-converges."""
@@ -467,3 +468,169 @@ def test_driver_mllm_checkpoint_bundles_everything(tmp_path):
     linked = [e for e in frozen if os.stat(
         os.path.join(last, e["file"])).st_nlink > 1]
     assert linked, "no frozen shard was reused across checkpoints"
+
+
+# ---------------------------------------------------------------------------
+# The lookahead: batch n+1 drawn while the device runs step n
+# ---------------------------------------------------------------------------
+
+class _Bundle:
+    """A health bundle whose host read goes into the log."""
+
+    def __init__(self, log, i, vals):
+        self.log, self.i, self.vals = log, i, vals
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("read", self.i))
+        return np.asarray(self.vals, dtype or np.float32)
+
+
+class _LoggedStream(CursorStream):
+    """Batch k is ``{"i": k}``; every draw the trainer makes is logged
+    (a seek's fast-forward is not)."""
+
+    def __init__(self, log, n=None):
+        super().__init__(lambda: iter(range(n if n is not None else 10**9)))
+        self.log = log
+
+    def next(self):
+        k = super().next()
+        self.log.append(("draw", k))
+        return {"i": np.int32(k)}
+
+
+def _toy_trainer(log, tmp=None, *, n=None, faults=(), ckpt_every=0,
+                 monitor=None, resume=False, on_device_loss=None):
+    """A trainer over a host step that logs the batch it saw and adds
+    its index to ``w`` (held back under an injected NaN), so the losses
+    spell out the order the batches trained in."""
+    def step(params, opt_state, health, batch, controls):
+        i = int(batch["i"])
+        bad = float(controls["inject_nan"]) > 0
+        log.append(("step", i))
+        w = params["w"]
+        new = params if bad else {"w": w + i}
+        return new, opt_state, health, _Bundle(
+            log, i, [float(w) + i, 1.0, 0.0, float(bad), float(not bad)])
+
+    return ResilientTrainer(
+        step, {"w": jnp.float32(0.0)}, {"n": jnp.int32(0)},
+        _LoggedStream(log, n), monitor=monitor,
+        manager=CheckpointManager(str(tmp)) if tmp is not None else None,
+        injector=FaultInjector(FaultPlan.make(list(faults))),
+        ckpt_every=ckpt_every, resume=resume,
+        on_device_loss=on_device_loss)
+
+
+def _run(tr, n, calls):
+    """``run(n)`` at once, or one step a call as the benchmark does."""
+    if calls == "one_call":
+        return tr.run(n)
+    while tr.step < n:
+        res = tr.run(tr.step + 1)
+    return res
+
+
+def _seen(log):
+    return [i for kind, i in log if kind == "step"]
+
+
+@pytest.mark.parametrize("calls", ["one_call", "step_by_step"])
+def test_lookahead_draws_the_next_batch_before_the_health_read(calls):
+    log = []
+    tr = _toy_trainer(log)
+    res = _run(tr, 5, calls)
+    assert _seen(log) == [0, 1, 2, 3, 4]         # batch k trains step k
+    want = [("draw", 0)]
+    for k in range(5):
+        want += [("step", k), ("draw", k + 1), ("read", k)]
+    assert log == want
+    assert res["batches_ahead"] == 4 and res["batches_in_series"] == 1
+    assert res["losses"] == {k: float(k * (k - 1) // 2 + k)
+                             for k in range(5)}
+    # batch 5 waits on the trainer, drawn but not consumed
+    assert tr.stream.cursor == 6 and tr.cursor == 5
+
+
+@pytest.mark.parametrize("calls", ["one_call", "step_by_step"])
+def test_finite_stream_runs_every_batch_and_ends_as_before(calls):
+    log = []
+    tr = _toy_trainer(log, n=4)
+    res = _run(tr, 4, calls)
+    assert _seen(log) == [0, 1, 2, 3] and res["last_step"] == 4
+    # the draw past the end found nothing: no batch waits
+    assert res["batches_ahead"] == 3 and tr.cursor == tr.stream.cursor == 4
+    with pytest.raises(StopIteration):
+        tr.run(5)
+    assert tr.step == 4
+
+
+@pytest.mark.parametrize("calls", ["one_call", "step_by_step"])
+def test_checkpoint_with_a_batch_ahead_records_consumed_cursor(
+        tmp_path, calls):
+    ref = _toy_trainer([]).run(8)["losses"]
+    tr = _toy_trainer([], tmp_path, faults=[Fault("crash", 5)],
+                      ckpt_every=2)
+    with pytest.raises(CrashInjected):
+        _run(tr, 8, calls)
+    # each save came with the next batch drawn ahead, not consumed
+    saves = tr.monitor.log.of_kind("checkpoint")
+    assert [(e["step"], e["cursor"]) for e in saves] == [(2, 2), (4, 4)]
+    assert tr.stream.cursor == 6
+    assert CheckpointManager(str(tmp_path)).peek_meta()["cursor"] == 4
+    tr2 = _toy_trainer([], tmp_path, resume=True)
+    assert tr2.step == tr2.cursor == tr2.stream.cursor == 4
+    post = _run(tr2, 8, calls)["losses"]
+    merged = {**{k: v for k, v in tr.losses.items() if k < 4}, **post}
+    assert merged == ref
+
+
+def _restore_case(case, log, tmp):
+    """(trainer after the event, the batches its steps saw, the batch
+    the pass after the event draws again) for each event that moves the
+    stream or the placement."""
+    if case == "rollback":
+        tr = _toy_trainer(log, tmp, faults=[Fault("nan_grads", 3)],
+                          ckpt_every=2,
+                          monitor=HealthMonitor(MonitorConfig(skip_limit=0)))
+        tr.run(6)
+        return tr, [0, 1, 2, 3, 2, 3, 4, 5], 2
+    if case == "device_loss":
+        tr = _toy_trainer(log, tmp, faults=[Fault("device_loss", 3)],
+                          ckpt_every=2)
+        tr.run(6)
+        return tr, [0, 1, 2, 2, 3, 4, 5], 2
+    if case == "device_loss_no_checkpoint":
+        lost = []
+        tr = _toy_trainer(log, faults=[Fault("device_loss", 3)],
+                          on_device_loss=lost.append)
+        tr.run(6)
+        assert lost == [1]
+        return tr, [0, 1, 2, 3, 4, 5], 3
+    tr = _toy_trainer(log)                       # adopt_state
+    tr.run(4)
+    tr.adopt_state({"w": jnp.float32(0.0)}, {"n": jnp.int32(0)},
+                   step=1, cursor=1)
+    assert tr.cursor == tr.stream.cursor == 1
+    tr.run(4)
+    return tr, [0, 1, 2, 3, 1, 2, 3], 1
+
+
+@pytest.mark.parametrize("case", ["rollback", "device_loss",
+                                  "device_loss_no_checkpoint", "adopt"])
+def test_restore_drops_the_batch_ahead(tmp_path, case):
+    log = []
+    tr, seen, redrawn = _restore_case(case, log, tmp_path)
+    assert _seen(log) == seen
+    res = tr.summary()
+    assert res["batches_in_series"] == 2
+    assert res["batches_ahead"] == len(seen) - 2
+    # the first pass, and the first after the event, draw their own
+    # batch just before they dispatch; the one after the event is the
+    # restored cursor's, drawn again though it had been drawn ahead
+    serial = [log[j][1] for j in range(1, len(log))
+              if log[j - 1] == ("draw", log[j][1]) and log[j][0] == "step"]
+    assert serial == [0, redrawn]
+    draws = [i for kind, i in log if kind == "draw"]
+    assert draws.count(redrawn) == 2
+    assert tr.cursor == tr.step == tr.stream.cursor - 1

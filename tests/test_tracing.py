@@ -22,8 +22,12 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "src", "repro")
 #: the benchmark's own span names, which the program must not take
 BENCHMARK_SPANS = {"data", "train_step", "window"}
-#: what the trainer writes inside each ``trainer.step``, in loop order
-STEP_SPANS = ["trainer.batch", "trainer.dispatch", "trainer.health_read"]
+#: what the trainer writes inside each ``trainer.step``, in loop order:
+#: the dispatch, the next step's batch drawn while the device runs this
+#: one, the host read; a pass with no batch drawn ahead (the first, and
+#: the first after a restore) draws its own batch first
+STEP_SPANS = ["trainer.dispatch", "trainer.batch", "trainer.health_read"]
+FIRST_STEP_SPANS = ["trainer.batch"] + STEP_SPANS
 SCOPES = ("encoder", "projector", "merge", "llm", "attention", "sdpa", "mlp",
           "lm_head", "optimizer", "health")
 _WRAPPED = re.compile(r"^[\w.\-]+\((.*)\)$")
@@ -45,8 +49,8 @@ def _batches():
 def spans(tmp_path_factory):
     """The trainer's spans, (start_ns, end_ns, name) in start order, from
     three steps with a save every two and a NaN step at step 2 that rolls
-    back to the save: iterations 0, 1 (+ save), 2 (rollback: restore),
-    2 again."""
+    back to the save: iterations 0, 1 (+ save), 2 (rollback: restore,
+    which drops the batch drawn ahead), 2 again."""
     ckpt = tmp_path_factory.mktemp("ckpt")
     log = tmp_path_factory.mktemp("trace")
     params = {"w": jnp.zeros((4, 1), jnp.float32)}
@@ -87,10 +91,13 @@ def _inside(spans, outer):
 def test_every_iteration_runs_its_spans_in_loop_order(spans):
     steps = [sp for sp in spans if sp[2] == "trainer.step"]
     assert len(steps) == 4
-    for sp in steps:
+    # the first pass and the first after the rollback's restore draw in
+    # series; the others dispatch on the batch drawn in the pass before
+    want = [FIRST_STEP_SPANS, STEP_SPANS, STEP_SPANS, FIRST_STEP_SPANS]
+    for sp, order in zip(steps, want):
         names = [n for _, _, n in _inside(spans, sp)
                  if n in STEP_SPANS]
-        assert names == STEP_SPANS, names
+        assert names == order, names
 
 
 def test_save_and_restore_spans_sit_in_their_iterations(spans):
@@ -108,9 +115,12 @@ def test_span_counts_are_the_work_done(spans):
              for n in ("trainer.step", "trainer.batch", "trainer.dispatch",
                        "trainer.health_read", "trainer.checkpoint",
                        "trainer.restore")}
-    # four iterations: four batches drawn, dispatches and host reads;
-    # one save, one restore; nothing outside a step
-    assert count == {"trainer.step": 4, "trainer.batch": 4,
+    # four iterations: four dispatches and host reads; six batches
+    # drawn, one ahead in each pass and one in series in the first and
+    # in the first after the restore, which dropped the batch drawn
+    # ahead of the rolled-back step; one save, one restore; nothing
+    # outside a step
+    assert count == {"trainer.step": 4, "trainer.batch": 6,
                      "trainer.dispatch": 4, "trainer.health_read": 4,
                      "trainer.checkpoint": 1, "trainer.restore": 1}
     steps = [sp for sp in spans if sp[2] == "trainer.step"]
