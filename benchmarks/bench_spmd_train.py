@@ -1,4 +1,4 @@
-"""Real-model SPMD training: distributed step time + dispatch compile
+"""Real-model SPMD training: distributed step time + runner compile
 scaling.
 
 Two childs, each in a subprocess with forced host devices (the same
@@ -12,11 +12,11 @@ harness the multi-device tests use):
   appears for a run that computed the right thing.
 
 * **compile** — times the first (trace + XLA compile) call of the
-  rolled instruction-table dispatch against the fully-unrolled switch
-  dispatch as the wave count grows with the microbatch count. The
-  rolled loop's compile time scales with *distinct* instructions, not
-  timeline length — the derived fields carry the wave counts so the
-  sublinear growth is visible in ``BENCH_spmd_train.json``.
+  runner's rolled instruction-table loop as the wave count grows with
+  the microbatch count. Its compile time scales with *distinct*
+  instructions, not timeline length — the derived fields carry the
+  wave counts so the sublinear growth is visible in
+  ``BENCH_spmd_train.json``.
 """
 import os
 import subprocess
@@ -104,16 +104,14 @@ for M in Ms:
     prog = compile_spmd_program(g, sim)
     fn, params = toy_stage_model(4, d)
     mbs = jax.random.normal(jax.random.PRNGKey(1), (M, 1, 4, d))
-    for dispatch in ("rolled", "switch"):
-        runner = build_spmd_runner(fn, g, sim, program=prog,
-                                   dispatch=dispatch)
-        t0 = time.perf_counter()
-        res = runner(params, mbs)
-        jax.block_until_ready(res["loss"])
-        us = (time.perf_counter() - t0) * 1e6
-        print(f"ROW spmdtrain/compile-{{dispatch}}-M{{M}} {{us:.1f}} "
-              f"dispatch={{dispatch}};microbatches={{M}};"
-              f"waves={{prog.counts()['waves']}}", flush=True)
+    runner = build_spmd_runner(fn, g, sim, program=prog)
+    t0 = time.perf_counter()
+    res = runner(params, mbs)
+    jax.block_until_ready(res["loss"])
+    us = (time.perf_counter() - t0) * 1e6
+    print(f"ROW spmdtrain/compile-M{{M}} {{us:.1f}} "
+          f"microbatches={{M}};waves={{prog.counts()['waves']}}",
+          flush=True)
 """
 
 
@@ -146,7 +144,7 @@ def run(smoke: bool = False):
     ms = (4, 8) if smoke else (4, 8, 16, 32)
     rows = _child(_CHILD_TRAIN.format(iters=2 if smoke else 5), 3)
     rows += _child(_CHILD_COMPILE.format(Ms=tuple(ms)), 4)
-    assert len(rows) == 1 + 2 * len(ms), rows
+    assert len(rows) == 1 + len(ms), rows
     return rows
 
 

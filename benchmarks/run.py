@@ -14,8 +14,8 @@ Emits ``name,us_per_call,derived`` CSV rows:
               (multi-device subprocess; fails loudly on grad or
               peak divergence)
   spmdtrain — real-MLLM SPMD train step (stage bundle through the
-              wave program) + rolled-vs-switch dispatch compile
-              scaling; writes BENCH_spmd_train.json
+              wave program) + the runner's compile time against wave
+              count; writes BENCH_spmd_train.json
   serve     — paged-cache serving throughput: tokens/sec vs batch
               size, xla gather vs paged flash-decode kernel, plus
               the multimodal page-skip fraction

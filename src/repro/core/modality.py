@@ -259,7 +259,7 @@ class MultimodalModule:
                 "inputs_embeds": embeds, "embed_mask": embed_mask}
 
     # -- single-program forward (reference; pipelined execution lives in
-    #    core/modality_parallel.py) -----------------------------------------
+    #    parallel/spmd.py) --------------------------------------------
     def forward(self, params, batch):
         enc_out = {}
         for name, enc in sorted(self.encoders.items()):
@@ -324,12 +324,11 @@ class MultimodalParallelSpec:
     def apply(self, mllm: MultimodalModule, text_len: int = 1024) -> dict:
         """Build the pipeline plan: per-module stage partitions (using
         the frozen-aware rule) + the modality-parallel graph + its
-        simulated schedule (any core.schedule scheduler). The shard_map
-        executor (core/modality_parallel.py) consumes plan["graph"],
-        which always has one stage per simulated device — chunked
-        schedules keep their v-times finer simulation for bubble
-        accounting but fold the executor graph back to the planned
-        partition.
+        simulated schedule (any core.schedule scheduler). The executor
+        contract's plan["graph"] always has one stage per simulated
+        device — chunked schedules keep their v-times finer simulation
+        for bubble accounting but fold the executor graph back to the
+        planned partition.
 
         Superseded by ``repro.parallel``: ``parallelize()`` searches
         the allocation instead of taking it as given, and

@@ -1,190 +1,23 @@
-"""Modality parallelism + pipeline execution on TPU/JAX (Cornstarch §4.1).
+"""Pipeline execution helpers on TPU/JAX (Cornstarch §4.1).
 
-Two complementary realizations of the paper's MPMD schedule in JAX's
-SPMD world (DESIGN.md §2):
-
-1. **Circular pipeline executor** (``pipeline_forward``): a single chain
-   of homogeneous stages mapped onto a ``stage`` mesh axis via
-   ``shard_map``. Microbatch ``m`` occupies stage ``s`` at tick
-   ``t = m + s``; activations advance with ``lax.ppermute`` inside a
-   ``lax.scan`` over ticks (the standard GPipe-on-TPU construction —
-   1F1B's memory policy is a scheduling refinement that SPMD ticks
-   subsume; bubble accounting for 1F1B / interleaved-1F1B / ZB-H1 /
-   ZB-V lives in core/schedule's simulator, and ``split_devices``
-   threads the schedule picked by Algorithm 1 through to the executor
-   plan). Autodiff through the scan gives the backward pipeline for
-   free.
-
-2. **Modality islands** (``ModalityIslands``): the paper's modality
-   parallelism proper — each encoder is jitted onto a *disjoint device
-   subset*; JAX's async dispatch overlaps their execution exactly
-   because the execution DAG has no edge between them (paper C1). The
-   LLM island consumes their outputs. On a real multi-pod TPU each
-   island is one pjit program over its submesh.
-
-3. **Schedule-driven executor** (``execute_schedule``): replays a
-   simulated F/B/W item timeline with real stage computations and real
-   VJPs, holding every inter-stage activation in an instrumented store
-   — the measurement side of the memory-validation harness
-   (``core.schedule.memory``), which cross-checks the simulator's
-   per-device peak-activation claims against execution.
-
-All are exercised by tests (subprocess, forced host device count) and
-by the Fig. 9/10-style benchmarks; the production dry-run proves the
-shard_map executor lowers on the (16, 16) mesh.
+``execute_schedule`` replays a simulated F/B/W item timeline with real
+stage computations and real VJPs, holding every inter-stage activation
+in an instrumented store. It is the measurement side of the
+memory-validation harness (``core.schedule.memory``), which
+cross-checks the simulator's per-device peak-activation claims against
+execution, and the sequential reference that the distributed executor,
+``repro.parallel.spmd.build_spmd_runner``, is held to schedule by
+schedule. ``normalize_stage_fns`` gives both executors one stage-fn
+contract, and ``split_devices`` hands out physical devices per module
+for a plan.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-# ---------------------------------------------------------------------------
-# 1. Circular pipeline executor (homogeneous stages, shard_map + ppermute)
-# ---------------------------------------------------------------------------
-
-def stack_stage_params(per_stage_params: Sequence[Any]):
-    """List of per-stage pytrees (identical structure) -> stage-stacked
-    pytree with leading S dim (shard P("stage") over it)."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_stage_params)
-
-
-def pipeline_forward(mesh: Mesh, axis_name: str, stage_fn: Callable,
-                     stage_params, microbatches, *, num_stages: int):
-    """Run ``y_m = stage_{S-1}(... stage_0(x_m))`` for every microbatch.
-
-    stage_fn(local_stage_params, x) -> y, with x/y of identical shape
-    (the residual-stream contract all our blocks obey).
-    stage_params: stage-stacked pytree (leading dim S).
-    microbatches: [M, ...] (replicated; stage 0 slices its tick's mb).
-    Returns [M, ...] outputs (gathered from the last stage).
-    """
-    M = microbatches.shape[0]
-    S = num_stages
-    ticks = M + S - 1
-
-    def body(local_params, mbs):
-        # local_params: leading dim 1 (this device's stage)
-        lp = jax.tree.map(lambda a: a[0], local_params)
-        sid = lax.axis_index(axis_name)
-        x0 = jnp.zeros_like(mbs[0])
-        out_buf = jnp.zeros_like(mbs)
-
-        def tick(carry, t):
-            x, out_buf = carry
-            mb_in_idx = jnp.clip(t, 0, M - 1)
-            fresh = lax.dynamic_index_in_dim(mbs, mb_in_idx, 0,
-                                             keepdims=False)
-            x = jnp.where(sid == 0, fresh, x)
-            y = stage_fn(lp, x)
-            # last stage writes finished microbatch t-(S-1) to the buffer
-            done_idx = jnp.clip(t - (S - 1), 0, M - 1)
-            write = (sid == S - 1) & (t >= S - 1)
-            cur = lax.dynamic_index_in_dim(out_buf, done_idx, 0,
-                                           keepdims=False)
-            out_buf = lax.dynamic_update_index_in_dim(
-                out_buf, jnp.where(write, y, cur), done_idx, 0)
-            perm = [(i, (i + 1) % S) for i in range(S)]
-            x = lax.ppermute(y, axis_name, perm)
-            return (x, out_buf), None
-
-        (x, out_buf), _ = lax.scan(tick, (x0, out_buf), jnp.arange(ticks))
-        # collect the filled buffer from the last stage on all devices
-        out_all = lax.all_gather(out_buf, axis_name)        # [S, M, ...]
-        return out_all[S - 1]
-
-    spec_params = jax.tree.map(
-        lambda a: P(axis_name, *([None] * (a.ndim - 1))), stage_params)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(spec_params, P(*([None] * microbatches.ndim))),
-        out_specs=P(*([None] * microbatches.ndim)),
-        check_vma=False,
-    )(stage_params, microbatches)
-
-
-def pipeline_reference(stage_fn: Callable, stage_params, microbatches, *,
-                       num_stages: int):
-    """Oracle: same math, no pipeline."""
-    def run_one(x):
-        for s in range(num_stages):
-            lp = jax.tree.map(lambda a: a[s], stage_params)
-            x = stage_fn(lp, x)
-        return x
-    return jax.vmap(run_one)(microbatches)
-
-
-# ---------------------------------------------------------------------------
-# 2. Modality islands: encoders on disjoint device subsets
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class Island:
-    name: str
-    devices: List[Any]               # jax devices owned by this island
-    fn: Callable                     # jitted on this island's devices
-    mesh: Optional[Mesh] = None
-
-
-class ModalityIslands:
-    """Place each encoder on its own device subset; the LLM on the rest.
-
-    ``run(params, batch)`` dispatches every encoder island asynchronously
-    (no dependency between them — the execution DAG guarantees it), then
-    feeds their outputs to the LLM island. With JAX async dispatch the
-    encoder computations overlap on real hardware; on CPU this verifies
-    correctness + device placement.
-    """
-
-    def __init__(self, mllm, device_split: Dict[str, List[Any]]):
-        from repro.models import mllm as M
-        self.mllm = mllm
-        self.islands: Dict[str, Island] = {}
-        for name, enc in mllm.encoders.items():
-            devs = device_split[name]
-            sh = NamedSharding(Mesh(np.array(devs), ("d",)), P())
-
-            def enc_fn(params, batch, enc=enc, sh=sh):
-                params = jax.device_put(params, sh)
-                return enc.forward(params, batch)
-
-            self.islands[name] = Island(name, devs,
-                                        jax.jit(enc_fn, static_argnums=()))
-        devs = device_split["llm"]
-        self.llm_sharding = NamedSharding(Mesh(np.array(devs), ("d",)), P())
-
-        def llm_fn(params, merged, mllm=mllm):
-            from repro.models import transformer as T
-            return T.forward(params, mllm.llm_cfg, merged)
-
-        self.llm_fn = jax.jit(llm_fn)
-
-    def run(self, params, batch):
-        # dispatch all encoder islands first — async, overlapping
-        futures = {}
-        for name, isl in self.islands.items():
-            futures[name] = isl.fn(params["encoders"][name], batch)
-        # cross-island transfer (the paper's encoder->LLM P2P send)
-        futures = {name: jax.device_put(out, self.llm_sharding)
-                   for name, out in futures.items()}
-        merged = self.mllm.build_merge(
-            jax.device_put(batch["text_tokens"], self.llm_sharding), futures)
-        llm_p = jax.device_put(params["llm"], self.llm_sharding)
-        return self.llm_fn(llm_p, merged)
-
-
-# ---------------------------------------------------------------------------
-# 3. Schedule-driven executor: replay a simulated item timeline with real
-#    stage computations (the memory-validation target)
-# ---------------------------------------------------------------------------
 
 def _accepts_microbatch(fn: Callable) -> bool:
     """Does ``fn`` implement the 3-arg StageFn contract
@@ -410,90 +243,16 @@ def _is_typed_plan(plan: Any) -> bool:
     return isinstance(plan, MLLMParallelPlan)
 
 
-def _dict_schedule_name(plan: Dict[str, Any]) -> Optional[str]:
-    """The schedule name a legacy plan dict carries, if any:
-    ``auto_parallelize`` results keep it under "schedule",
-    ``MultimodalParallelSpec.apply`` plans keep the sim dict there and
-    the name under "schedule_name"."""
-    name = plan.get("schedule")
-    if not isinstance(name, str):
-        name = plan.get("schedule_name")
-    return name if isinstance(name, str) else None
-
-
-def schedule_from_plan(plan: Any) -> str:
-    """DEPRECATED shim — read ``plan.schedule.name`` off an
-    ``MLLMParallelPlan`` instead. Accepts the typed plan, the two
-    legacy dict flavors (``auto_parallelize`` result /
-    ``MultimodalParallelSpec.apply``), or None (no plan -> classic
-    1F1B). A dict that carries no recognizable schedule, or a name
-    outside ``core.schedule.SCHEDULES``, raises ``ValueError`` — the
-    silent-1F1B default masked genuinely malformed plans."""
-    import warnings
-    warnings.warn(
-        "schedule_from_plan is deprecated; use "
-        "repro.parallel.MLLMParallelPlan and plan.schedule.name",
-        DeprecationWarning, stacklevel=2)
-    from repro.core.schedule import SCHEDULES
-    if plan is None:
-        return "1f1b"
-    if _is_typed_plan(plan):
-        return plan.schedule.name
-    if isinstance(plan, dict):
-        name = _dict_schedule_name(plan)
-        if name in SCHEDULES:
-            return name
-        raise ValueError(
-            f"plan carries no recognizable schedule (got {name!r}, "
-            f"valid: {SCHEDULES}); pass an MLLMParallelPlan, an "
-            "auto_parallelize result, or a MultimodalParallelSpec."
-            "apply dict")
-    raise ValueError(f"not a plan: {type(plan).__name__!r}")
-
-
-def virtual_chunks_from_plan(plan: Any) -> int:
-    """DEPRECATED shim — read ``plan.schedule.virtual_chunks`` off an
-    ``MLLMParallelPlan`` instead. Same accepted flavors as
-    ``schedule_from_plan``; a recognized plan without the tag (both
-    legacy flavors always carry it) defaults to 1, anything malformed
-    raises ``ValueError``."""
-    import warnings
-    warnings.warn(
-        "virtual_chunks_from_plan is deprecated; use "
-        "repro.parallel.MLLMParallelPlan and "
-        "plan.schedule.virtual_chunks",
-        DeprecationWarning, stacklevel=2)
-    from repro.core.schedule import SCHEDULES
-    if plan is None:
-        return 1
-    if _is_typed_plan(plan):
-        return plan.schedule.virtual_chunks
-    if isinstance(plan, dict):
-        v = plan.get("virtual_chunks")
-        if isinstance(v, int) and v >= 1:
-            return v
-        if v is None and _dict_schedule_name(plan) in SCHEDULES:
-            return 1
-        raise ValueError(f"plan carries no usable virtual_chunks "
-                         f"(got {v!r})")
-    raise ValueError(f"not a plan: {type(plan).__name__!r}")
-
-
 def split_devices(mllm, devices: Sequence[Any],
                   plan: Any = None) -> Dict[str, list]:
     """Assign device counts per module (default: 1 per encoder, rest to
-    the LLM). ``plan`` is an ``MLLMParallelPlan`` (the typed API), a
-    plain {encoder_name: count} dict, or the legacy result dict of
-    ``core.pipeline.auto_parallelize``, whose per-encoder stage counts
-    are matched by the "encoder_names" it carries. The winning schedule
-    travels on the typed plan (``plan.schedule``); this dict stays
-    purely {module: device list}."""
+    the LLM). ``plan`` is an ``MLLMParallelPlan`` (its
+    ``stage_counts_by_name()``), a plain {encoder_name: count} dict, or
+    None. The winning schedule travels on the typed plan
+    (``plan.schedule``); this dict stays purely {module: device list}."""
     devices = list(devices)
     if _is_typed_plan(plan):
         plan = plan.stage_counts_by_name()
-    elif plan and "encoder_stages" in plan:   # auto_parallelize result
-        names = plan.get("encoder_names") or sorted(mllm.encoders)
-        plan = dict(zip(names, plan["encoder_stages"]))
     plan = plan or {name: 1 for name in mllm.encoders}
     out: Dict[str, list] = {}
     i = 0

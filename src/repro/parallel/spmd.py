@@ -7,14 +7,15 @@ VJPs, an instrumented activation store — but never crosses a device
 boundary. This module is the distributed counterpart: the same
 timeline, compiled to a static SPMD program and executed under
 ``shard_map`` on a named mesh, with every stage handoff (forward
-activation, backward cotangent) carried by ``lax.ppermute``.
+activation, backward cotangent) carried between devices at a wave
+boundary.
 
 Compilation (``compile_spmd_program``) turns the timeline into
 **waves**: a wave holds at most one work item per device (devices
 whose next item is not yet dependency-ready sit the wave out — that
 is the pipeline bubble, now visible as an idle branch), and each wave
 boundary carries the activations/cotangents the wave just produced as
-one or more ppermute **rounds** (a round is a partial permutation:
+one or more comm **rounds** (a round is a partial permutation:
 distinct sources, distinct destinations; fan-in DAGs that route two
 encoder outputs to the same LLM device in one boundary simply take two
 rounds). The compiled program is plain data — ``repro.analysis.
@@ -28,18 +29,20 @@ store with a boolean occupancy mask (the *measured* container, exactly
 like ``execute_schedule``'s dict store), an inbox accumulating fan-in
 partial sums, a cotangent accumulator for fan-out stages, W-residual
 slots for deferred weight-grad passes — and steps through the waves
-with a steady-state rolled loop: a ``lax.fori_loop`` over a compacted
+with one rolled loop: a ``lax.fori_loop`` over a compacted
 instruction table dispatching one ``lax.switch`` over *distinct*
 ``(kind, stage)`` branches, so compile time scales with the number of
-distinct instructions rather than timeline length (the fully-unrolled
-``dispatch="switch"`` baseline is kept for comparison). Stage fns may
-be real-model per-stage callables (``models.stages.build_mllm_stages``
-— heterogeneous params travel as a replicated list; only the leaves a
-trainable stage's cotangent reaches hold, accumulate and psum-reduce a
-weight gradient, the rest come back as zeros) or a single homogeneous
-callable. Each work item runs under ``jax.named_scope`` ``stage{s}`` >
-``F``/``B``/``W``, each wave boundary's comm rounds under ``handoff``
-and the closing reductions under ``pipeline_reduce``. Loss and outputs are
+distinct instructions rather than timeline length; each round is one
+``all_gather`` from which every receiver takes its source's buffer.
+``execute_schedule`` is the reference the tests hold it to, schedule
+by schedule. Stage fns may be real-model per-stage callables
+(``models.stages.build_mllm_stages`` — heterogeneous params travel as
+a replicated list; only the leaves a trainable stage's cotangent
+reaches hold, accumulate and psum-reduce a weight gradient, the rest
+come back as zeros) or a single homogeneous callable. Each work item
+runs under ``jax.named_scope`` ``stage{s}`` > ``F``/``B``/``W``, each
+wave boundary's comm rounds under ``handoff`` and the closing
+reductions under ``pipeline_reduce``. Loss and outputs are
 ``psum``-reduced over the pipeline axis; per-item occupancy is written
 into a trace buffer and reassembled host-side into the same
 ``activation_trace`` format ``execute_schedule`` returns, so
@@ -90,9 +93,9 @@ class Transfer:
 
 @dataclasses.dataclass
 class CommRound:
-    """One ``lax.ppermute`` call at a wave boundary. Sources and
-    destinations are distinct within a round (a partial permutation —
-    the ppermute contract)."""
+    """One comm round at a wave boundary, run as one ``all_gather``.
+    Sources and destinations are distinct within a round (a partial
+    permutation: each device sends and receives at most one buffer)."""
     kind: str                        # "fwd" | "bwd"
     transfers: List[Transfer]
 
@@ -132,7 +135,7 @@ class SPMDProgram:
 
 
 # ---------------------------------------------------------------------------
-# Compilation: timeline -> waves + ppermute rounds
+# Compilation: timeline -> waves + comm rounds
 # ---------------------------------------------------------------------------
 
 def compile_spmd_program(graph: PipelineGraph,
@@ -384,8 +387,8 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                       microbatch_loss: Optional[Callable] = None,
                       program: Optional[SPMDProgram] = None,
                       jit: bool = True,
-                      trainable: Optional[Sequence[bool]] = None,
-                      dispatch: str = "rolled") -> Callable:
+                      trainable: Optional[Sequence[bool]] = None
+                      ) -> Callable:
     """Compile the schedule once and return
     ``runner(stage_params, microbatches) -> result dict`` with the same
     contract as ``execute_schedule`` (outputs, loss, param_grads,
@@ -404,20 +407,13 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
     has ``execute_schedule``'s semantics (stages that must produce
     weight grads even with ``bwd_w == 0``).
 
-    ``dispatch`` selects the wave-stepping strategy:
-
-    * ``"rolled"`` (default): a ``lax.fori_loop`` over waves indexing a
-      compacted instruction table, with one ``lax.switch`` over
-      *distinct* ``(kind, stage)`` branches and table-driven
-      ``all_gather`` comm rounds — compile time scales with distinct
-      instructions, not timeline length.
-    * ``"switch"``: the original fully-unrolled one-``lax.switch``-per-
-      wave program with per-round ``ppermute`` — retraces every wave;
-      kept as the compile-time baseline (see
-      ``benchmarks/bench_spmd_train.py``).
-
-    Both dispatch modes execute the exact same per-item updates in the
-    same order — identical loss, grads, occupancy trace, and peaks.
+    The waves run as a ``lax.fori_loop`` indexing a compacted
+    instruction table (``_rolled_tables``): one ``lax.switch`` over the
+    *distinct* ``(kind, stage)`` branches, then the wave's comm rounds,
+    each an ``all_gather`` of the producers' buffers from which every
+    receiver picks its source by table lookup. Compile time scales with
+    the number of distinct instructions, not with timeline length, and
+    the per-item updates are ``execute_schedule``'s, in its order.
     """
     from repro.core.modality_parallel import normalize_stage_fns
     prog = program if program is not None else \
@@ -429,8 +425,6 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
             f"mesh axis {axis_name!r} has {mesh.shape[axis_name]} "
             f"devices but the program was compiled for "
             f"{prog.num_devices}")
-    if dispatch not in ("rolled", "switch"):
-        raise ValueError(f"unknown dispatch {dispatch!r}")
     loss_fn = microbatch_loss or (lambda y: jnp.mean(y ** 2))
     S = len(graph.stages)
     D, L = prog.num_devices, prog.max_chunks
@@ -599,87 +593,44 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                     return st
                 return br
 
-            if dispatch == "rolled":
-                keys, instr, m_tab, item_tab, R, comm = \
-                    _rolled_tables(prog)
-                branches = [idle] + [make_branch(k, s) for k, s in keys]
-                instr_a = jnp.asarray(instr)
-                m_a = jnp.asarray(m_tab)
-                item_a = jnp.asarray(item_tab)
-                if R:
-                    on_t, src_t, c_t, m2_t, isb_t = comm
-                    on_a, src_a = jnp.asarray(on_t), jnp.asarray(src_t)
-                    c_a, m2_a = jnp.asarray(c_t), jnp.asarray(m2_t)
-                    isb_a = jnp.asarray(isb_t)
+            keys, instr, m_tab, item_tab, R, comm = _rolled_tables(prog)
+            branches = [idle] + [make_branch(k, s) for k, s in keys]
+            instr_a = jnp.asarray(instr)
+            m_a = jnp.asarray(m_tab)
+            item_a = jnp.asarray(item_tab)
+            if R:
+                on_t, src_t, c_t, m2_t, isb_t = comm
+                on_a, src_a = jnp.asarray(on_t), jnp.asarray(src_t)
+                c_a, m2_a = jnp.asarray(c_t), jnp.asarray(m2_t)
+                isb_a = jnp.asarray(isb_t)
 
-                @jax.named_scope("handoff")
-                def comm_rounds(w, st):
-                    def round_body(r, st):
-                        st = dict(st)
-                        isb = isb_a[w, r]
-                        buf = jnp.where(isb, st["bg"], st["fy"])
-                        gathered = lax.all_gather(buf, axis_name)
-                        recv = gathered[src_a[w, r, idx]]
-                        onv = on_a[w, r, idx]
-                        cc, mm = c_a[w, r, idx], m2_a[w, r, idx]
-                        delta = jnp.where(onv, recv,
-                                          jnp.zeros_like(recv))
-                        zero = jnp.zeros_like(delta)
-                        st["inbox"] = st["inbox"].at[cc, mm].add(
-                            jnp.where(isb, zero, delta))
-                        st["cot"] = st["cot"].at[cc, mm].add(
-                            jnp.where(isb, delta, zero))
-                        return st
-                    return lax.fori_loop(0, R, round_body, st)
-
-                def wave_body(w, st):
-                    st = lax.switch(instr_a[w, idx], branches, st,
-                                    m_a[w, idx], item_a[w, idx])
-                    if R:
-                        st = comm_rounds(w, st)
+            @jax.named_scope("handoff")
+            def comm_rounds(w, st):
+                def round_body(r, st):
+                    st = dict(st)
+                    isb = isb_a[w, r]
+                    buf = jnp.where(isb, st["bg"], st["fy"])
+                    gathered = lax.all_gather(buf, axis_name)
+                    recv = gathered[src_a[w, r, idx]]
+                    onv = on_a[w, r, idx]
+                    cc, mm = c_a[w, r, idx], m2_a[w, r, idx]
+                    delta = jnp.where(onv, recv, jnp.zeros_like(recv))
+                    zero = jnp.zeros_like(delta)
+                    st["inbox"] = st["inbox"].at[cc, mm].add(
+                        jnp.where(isb, zero, delta))
+                    st["cot"] = st["cot"].at[cc, mm].add(
+                        jnp.where(isb, delta, zero))
                     return st
+                return lax.fori_loop(0, R, round_body, st)
 
-                state = lax.fori_loop(0, len(prog.waves), wave_body,
-                                      state)
-            else:                                # dispatch == "switch"
-                stage_br: Dict[Tuple[str, int], Callable] = {}
+            def wave_body(w, st):
+                st = lax.switch(instr_a[w, idx], branches, st,
+                                m_a[w, idx], item_a[w, idx])
+                if R:
+                    st = comm_rounds(w, st)
+                return st
 
-                def static_branch(d, instr):
-                    i, kind, s, _c, m = instr
-                    if (kind, s) not in stage_br:
-                        stage_br[(kind, s)] = make_branch(kind, s)
-                    br = stage_br[(kind, s)]
-                    return lambda st, br=br, m=m, i=i: br(
-                        st, jnp.int32(m), jnp.int32(i))
-
-                @jax.named_scope("handoff")
-                def handoff(state, rnd):
-                    state = dict(state)
-                    buf = state["fy"] if rnd.kind == "fwd" else state["bg"]
-                    recv = lax.ppermute(buf, axis_name, rnd.pairs)
-                    on = [False] * D
-                    cs = [0] * D
-                    ms = [0] * D
-                    for t in rnd.transfers:
-                        on[t.dst_dev] = True
-                        cs[t.dst_dev] = chunk_of[t.dst_stage]
-                        ms[t.dst_dev] = t.microbatch
-                    c = jnp.asarray(cs)[idx]
-                    m = jnp.asarray(ms)[idx]
-                    delta = jnp.where(jnp.asarray(on)[idx], recv,
-                                      jnp.zeros_like(recv))
-                    key = "inbox" if rnd.kind == "fwd" else "cot"
-                    state[key] = state[key].at[c, m].add(delta)
-                    return state
-
-                for wave in prog.waves:
-                    branches = [static_branch(d, wave.compute[d])
-                                if d in wave.compute
-                                else (lambda st: st)
-                                for d in range(D)]
-                    state = lax.switch(idx, branches, state)
-                    for rnd in wave.rounds:
-                        state = handoff(state, rnd)
+            state = lax.fori_loop(0, len(prog.waves), wave_body, state)
 
             with jax.named_scope("pipeline_reduce"):
                 outputs = lax.psum(state["out"], axis_name)
@@ -770,11 +721,6 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
 # Public entry points
 # ---------------------------------------------------------------------------
 
-def _is_typed_plan(obj: Any) -> bool:
-    from repro.parallel.plan import MLLMParallelPlan
-    return isinstance(obj, MLLMParallelPlan)
-
-
 def run_schedule_spmd(*args: Any, mesh: Optional[Mesh] = None,
                       axis_name: str = "pp",
                       microbatch_loss: Optional[Callable] = None,
@@ -782,7 +728,6 @@ def run_schedule_spmd(*args: Any, mesh: Optional[Mesh] = None,
                       stage_fn: Any = None,
                       stage_params: Any = None,
                       trainable: Optional[Sequence[bool]] = None,
-                      dispatch: str = "rolled",
                       seed: int = 0) -> Dict[str, Any]:
     """Execute a schedule timeline distributed under ``shard_map``.
 
@@ -808,6 +753,7 @@ def run_schedule_spmd(*args: Any, mesh: Optional[Mesh] = None,
     param_grads, per-device peaks, activation_trace) plus the compiled
     ``program``.
     """
+    from repro.core.modality_parallel import _is_typed_plan
     if _is_typed_plan(args[0]):
         plan, mllm, microbatches = args
         executor = plan.apply(mllm, mode="spmd")
@@ -836,8 +782,7 @@ def run_schedule_spmd(*args: Any, mesh: Optional[Mesh] = None,
     runner = build_spmd_runner(stage_fn, graph, sim, mesh=mesh,
                                axis_name=axis_name,
                                microbatch_loss=microbatch_loss,
-                               program=prog, trainable=trainable,
-                               dispatch=dispatch)
+                               program=prog, trainable=trainable)
     return runner(stage_params, microbatches)
 
 

@@ -216,7 +216,7 @@ def make_spmd_train_step(stage_fn, graph, sim,
                          mesh=None, axis_name: str = "pp",
                          microbatch_loss=None, frozen_mask=None,
                          trainable=None, grad_scale: float = 1.0,
-                         dispatch: str = "rolled", program=None):
+                         program=None):
     """Pipeline-parallel train step driven by a simulated schedule
     timeline, executed distributed (``repro.parallel.spmd``).
 
@@ -240,8 +240,7 @@ def make_spmd_train_step(stage_fn, graph, sim,
     runner = build_spmd_runner(stage_fn, graph, sim, mesh=mesh,
                                axis_name=axis_name,
                                microbatch_loss=microbatch_loss,
-                               trainable=trainable, dispatch=dispatch,
-                               program=program)
+                               trainable=trainable, program=program)
 
     def step(stage_params, opt_state, microbatches):
         res = runner(stage_params, microbatches)

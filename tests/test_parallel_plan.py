@@ -1,7 +1,7 @@
 """repro.parallel: the typed parallelize() entrypoint and the
 MLLMParallelPlan it returns — search parity with Algorithm 1, JSON
 round-trips, the golden 8-rank paper_mllm plan, the executor
-fold-back contract, and the deprecated-shim interop."""
+fold-back contract, and ``split_devices`` placement."""
 import json
 import os
 
@@ -294,3 +294,13 @@ def test_split_devices_accepts_typed_plan(paper_vlm, paper_plan):
     assert len(split["vision"]) == \
         paper_plan.stage_counts_by_name()["vision"]
     assert len(split["llm"]) == paper_plan.stage.llm_stages
+
+
+def test_split_devices_respects_plan():
+    from repro.core.modality_parallel import split_devices
+    from repro.models.mllm import build_paper_mllm
+    mllm = build_paper_mllm("valm", reduced=True)
+    split = split_devices(mllm, list(range(8)),
+                          plan={"vision": 2, "audio": 1})
+    assert len(split["vision"]) == 2 and len(split["audio"]) == 1
+    assert len(split["llm"]) == 5

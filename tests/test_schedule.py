@@ -400,11 +400,9 @@ def test_parallel_spec_threads_schedule():
 def test_parallel_spec_graph_stays_one_stage_per_device():
     """Executor contract: plan["graph"] always has one stage per
     simulated device — interleaved's v-times finer simulation graph
-    must fold back to the planned partition, and schedule_from_plan
-    resolves the name from the apply-plan flavor too."""
+    must fold back to the planned partition."""
     from repro.core.modality import (ModalityModule, MultimodalModule,
                                      MultimodalParallelSpec, ParallelSpec)
-    from repro.core.modality_parallel import schedule_from_plan
     from repro.configs.paper_mllm import llm_config, vision_encoder_config
     mllm = MultimodalModule(
         encoders={"vision": ModalityModule(
@@ -421,12 +419,11 @@ def test_parallel_spec_graph_stays_one_stage_per_device():
         plan = spec.apply(mllm, text_len=256)
         assert len(plan["graph"].stages) == \
             plan["schedule"]["num_devices"], schedule
-        with pytest.warns(DeprecationWarning):
-            assert schedule_from_plan(plan) == schedule
 
 
 def test_split_devices_accepts_auto_parallelize_plan():
     from repro.core import modality_parallel as mp
+    from repro.parallel import MLLMParallelPlan, SchedulePlan, StagePlan
 
     class FakeMLLM:
         encoders = {"audio": None, "vision": None}
@@ -435,41 +432,15 @@ def test_split_devices_accepts_auto_parallelize_plan():
     # on the right encoder even when that order is not name-sorted;
     # stage counts stay COARSE (one per device) even for chunked
     # schedules — virtual chunks fold onto the same devices
-    plan = {"encoder_stages": [2, 1], "encoder_names": ["vision", "audio"],
-            "schedule": "zb-v", "virtual_chunks": 2, "llm_stages": 3}
+    plan = MLLMParallelPlan(
+        stage=StagePlan(encoder_names=("vision", "audio"),
+                        encoder_stages=(2, 1), llm_stages=3),
+        schedule=SchedulePlan(
+            name="zb-v", virtual_chunks=2, num_microbatches=8,
+            iteration_time=1.0, bubble_fraction=0.1, num_devices=6,
+            peak_activations_per_device=(1,) * 6, tput_per_device=1.0),
+        context=None, text_len=64)
+    assert plan.schedule.name == "zb-v"
+    assert plan.schedule.virtual_chunks == 2
     split = mp.split_devices(FakeMLLM(), list(range(6)), plan=plan)
-    assert len(split["vision"]) == 2 and len(split["audio"]) == 1
-    assert len(split["llm"]) == 3
-    assert all(isinstance(v, list) for v in split.values())
-    with pytest.warns(DeprecationWarning):
-        assert mp.schedule_from_plan(plan) == "zb-v"
-    with pytest.warns(DeprecationWarning):
-        assert mp.virtual_chunks_from_plan(plan) == 2
-
-
-def test_plan_shims_deprecate_and_reject_malformed():
-    """The legacy string-digging shims survive only as deprecated
-    adapters: every call warns, None still means "no plan" (classic
-    1F1B), and a dict that carries no recognizable schedule raises
-    instead of silently defaulting to 1f1b."""
-    from repro.core import modality_parallel as mp
-    with pytest.warns(DeprecationWarning):
-        assert mp.schedule_from_plan(None) == "1f1b"
-    with pytest.warns(DeprecationWarning):
-        assert mp.virtual_chunks_from_plan(None) == 1
-    # apply-flavor dicts resolve through schedule_name
-    with pytest.warns(DeprecationWarning):
-        assert mp.schedule_from_plan(
-            {"schedule": {"iteration_time": 1.0},
-             "schedule_name": "interleaved"}) == "interleaved"
-    # a recognized plan flavor without the chunk tag defaults to 1
-    with pytest.warns(DeprecationWarning):
-        assert mp.virtual_chunks_from_plan({"schedule": "1f1b"}) == 1
-    for bad in ({"vision": 1}, {"schedule": "gpipe"}, 17, "zb-v"):
-        with pytest.warns(DeprecationWarning), \
-                pytest.raises(ValueError):
-            mp.schedule_from_plan(bad)
-    for bad in ({"vision": 1}, {"virtual_chunks": 0}, 17):
-        with pytest.warns(DeprecationWarning), \
-                pytest.raises(ValueError):
-            mp.virtual_chunks_from_plan(bad)
+    assert split == {"audio": [0], "vision": [1, 2], "llm": [3, 4, 5]}

@@ -19,7 +19,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import schedule as sch
 from repro.core.modality_parallel import execute_schedule
 from repro.data.synthetic import MultimodalDataset
 from repro.optim import optimizer as opt
@@ -37,25 +36,28 @@ LAUNCH_ARGS = ["--mllm", "vlm", "--reduced", "--steps", "4",
                "--log-every", "0"]
 
 
-#: the cases the stage partition is checked at: the reduced paper LLM;
-#: Qwen2-VL's attention shape (q/k/v biases, 7 query heads to each KV
-#: head; the reduced LLM's head is already untied, with no QK-norm); and
-#: the ft1 fine-tune, whose trainable LLM stages take weight grads
-CASES = {"paper": ({}, False),
-         "qwen2vl": ({"num_heads": 7, "num_kv_heads": 1, "head_dim": 32,
-                      "qkv_bias": True}, False),
-         "ft1": ({}, True)}
+#: the cases the stage partition is checked at, as (paper MLLM, LLM
+#: shape, train the LLM): the reduced paper VLM; Qwen2-VL's attention
+#: shape (q/k/v biases, 7 query heads to each KV head; the reduced LLM's
+#: head is already untied, with no QK-norm); the ft1 fine-tune, whose
+#: trainable LLM stages take weight grads; and the VALM, whose two
+#: frozen towers run on different ranks and both feed the LLM
+CASES = {"paper": ("vlm", {}, False),
+         "qwen2vl": ("vlm", {"num_heads": 7, "num_kv_heads": 1,
+                             "head_dim": 32, "qkv_bias": True}, False),
+         "ft1": ("vlm", {}, True),
+         "valm": ("valm", {}, False)}
 
 
 @functools.lru_cache(maxsize=None)
 def tiny_case(case="paper"):
-    """A real (reduced) VLM + a searched plan + its SPMD executor
+    """A real (reduced) paper MLLM + a searched plan + its SPMD executor
     contract — the fixture every test here partitions. Cached per
     process: the plan search and stage build are deterministic."""
     from repro.models.mllm import build_paper_mllm
     from repro.parallel import ClusterSpec, WorkloadShape, parallelize
-    mllm = build_paper_mllm("vlm", reduced=True, text_len=TEXT)
-    shape, train_llm = CASES[case]
+    kind, shape, train_llm = CASES[case]
+    mllm = build_paper_mllm(kind, reduced=True, text_len=TEXT)
     mllm.llm_cfg = mllm.llm_cfg.replace(**shape)
     assert not mllm.llm_cfg.tie_embeddings and not mllm.llm_cfg.use_qk_norm
     if train_llm:
@@ -180,6 +182,20 @@ def test_spmd_runner_trains_real_mllm(case):
     bundle = ex["stage_bundle"]
     D = int(ex["schedule"]["num_devices"])
     mesh = mesh_from_plan(plan, mllm, D)
+    if case == "valm":
+        # each tower's frozen first stage on a rank of its own, and
+        # both towers' outputs summed into the LLM's first stage
+        specs, device_of = bundle.specs, ex["schedule"]["device_of"]
+        towers = [s for s, sp in enumerate(specs)
+                  if sp.kind == "encoder" and sp.first]
+        assert sorted(specs[s].module for s in towers) == \
+            ["audio", "vision"]
+        assert not any(bundle.trainable[s] for s in towers)
+        assert len({device_of[s] for s in towers}) == 2
+        llm0 = next(s for s, sp in enumerate(specs)
+                    if sp.kind == "llm" and sp.first)
+        assert sorted(specs[p].module for p in
+                      ex["sim_graph"].preds[llm0]) == ["audio", "vision"]
     params = mllm.init(jax.random.PRNGKey(0))
     batch = tiny_batch(mllm)
     ref_loss, ref_grads = reference_loss_grads(mllm, params, batch)
@@ -224,31 +240,6 @@ def test_spmd_runner_trains_real_mllm(case):
     for mk, old, new in zip(masks, sp, new_sp):
         jax.tree.map(check_move, mk, old, new)
     assert moved[0] > 0            # the projectors actually trained
-
-
-@subprocess_test(4)
-def test_rolled_dispatch_matches_switch_dispatch():
-    """The compacted rolled loop and the unrolled switch program are
-    the same executor: identical trace/peaks, equal loss and grads."""
-    from repro.parallel.spmd import run_schedule_spmd, toy_stage_model
-    stages = [sch.Stage(f"s{i}", 1.0, 2.0, bwd_w=1.0) for i in range(4)]
-    g = sch.chain_graph(stages)
-    sim = sch.get_scheduler("zb-h1").simulate(g, 8)
-    fn, params = toy_stage_model(4, 16)
-    mbs = jax.random.normal(jax.random.PRNGKey(7), (8, 1, 4, 16))
-    rolled = run_schedule_spmd(fn, params, mbs, g, sim,
-                               dispatch="rolled")
-    switch = run_schedule_spmd(fn, params, mbs, g, sim,
-                               dispatch="switch")
-    np.testing.assert_allclose(float(rolled["loss"]),
-                               float(switch["loss"]), rtol=1e-6)
-    assert rolled["activation_trace"] == switch["activation_trace"]
-    assert rolled["peak_activations_per_device"] == \
-        switch["peak_activations_per_device"]
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        rolled["param_grads"], switch["param_grads"])
 
 
 @subprocess_test(3)
