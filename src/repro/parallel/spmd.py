@@ -34,13 +34,17 @@ instruction table dispatching one ``lax.switch`` over *distinct*
 ``(kind, stage)`` branches, so compile time scales with the number of
 distinct instructions rather than timeline length; each round is one
 ``all_gather`` from which every receiver takes its source's buffer.
+The switch carries one microbatch's slices, never the stores (a
+conditional copies every large operand it is handed): the wave reads
+its slots before it and updates the stores in place after it.
 ``execute_schedule`` is the reference the tests hold it to, schedule
 by schedule. Stage fns may be real-model per-stage callables
 (``models.stages.build_mllm_stages`` — heterogeneous params travel as
 a replicated list; only the leaves a trainable stage's cotangent
 reaches hold, accumulate and psum-reduce a weight gradient, the rest
 come back as zeros) or a single homogeneous callable. Each work item
-runs under ``jax.named_scope`` ``stage{s}`` > ``F``/``B``/``W``, each
+runs under ``jax.named_scope`` ``stage{s}`` > ``F``/``B``/``W``, the
+store reads and updates under ``store``, each
 wave boundary's comm rounds under ``handoff`` and the closing
 reductions under ``pipeline_reduce``. Loss and outputs are
 ``psum``-reduced over the pipeline axis; per-item occupancy is written
@@ -343,6 +347,49 @@ def _rolled_tables(prog: SPMDProgram):
     return keys, instr, m_tab, item_tab, R, comm
 
 
+def _store_tables(prog: SPMDProgram, keys: Sequence[Tuple[str, int]],
+                  trainable: Sequence[bool]) -> Dict[str, np.ndarray]:
+    """What each instruction does to the device's stores, indexed like
+    the ``lax.switch`` branches (row 0 is the idle branch, which does
+    nothing): its kind, its chunk, whether it reads the microbatch
+    (source F), feeds the loss (sink F), hands ``y`` to the wave's fwd
+    (``fy``) or bwd (``bg``) rounds, or parks its residuals for a W; and
+    up to K local successors (F) or predecessors (B) whose inbox or
+    cotangent slot takes ``y`` — fan-in plans put several on one
+    device."""
+    graph, dev_of, chunk_of = prog.graph, prog.device_of, prog.chunk_of
+
+    def local(ss, s):
+        return [chunk_of[q] for q in ss if dev_of[q] == dev_of[s]]
+    to: List[List[int]] = [[]]
+    t: Dict[str, List[Any]] = {n: [False] for n in (
+        "F", "B", "W", "src", "sink", "fy", "bg", "park")}
+    t["c"] = [0]
+    for kind, s in keys:
+        stg = graph.stages[s]
+        prs, sucs = graph.preds[s], graph.succs[s]
+        bwd_b = kind == "B" and stg.bwd_b > 0 and bool(prs)
+        row = {"F": kind == "F", "B": kind == "B", "W": kind == "W",
+               "src": kind == "F" and not prs,
+               "sink": kind == "F" and not sucs,
+               "fy": kind == "F" and bool(sucs), "bg": bwd_b,
+               "park": (kind == "B" and trainable[s] and prog.has_w_items
+                        and stg.bwd_w > 0),
+               "c": chunk_of[s]}
+        for n, v in row.items():
+            t[n].append(v)
+        to.append(local(sucs, s) if kind == "F" else
+                  local(prs, s) if bwd_b else [])
+    K = max(1, max(len(r) for r in to))
+    out = {n: np.asarray(v, np.int32 if n == "c" else bool)
+           for n, v in t.items()}
+    out["to_c"] = np.asarray([r + [0] * (K - len(r)) for r in to],
+                             np.int32)
+    out["to_on"] = np.asarray([[k < len(r) for k in range(K)]
+                               for r in to], bool)
+    return out
+
+
 def _grad_support(fn: Callable, lp: Any, x: Any, mb: Any) -> List[int]:
     """Indices of the leaves of ``lp`` (a stage's params) whose weight
     gradient can be nonzero. A VJP is linear in its cotangent, so a
@@ -412,8 +459,10 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
     *distinct* ``(kind, stage)`` branches, then the wave's comm rounds,
     each an ``all_gather`` of the producers' buffers from which every
     receiver picks its source by table lookup. Compile time scales with
-    the number of distinct instructions, not with timeline length, and
-    the per-item updates are ``execute_schedule``'s, in its order.
+    the number of distinct instructions, not with timeline length. The
+    branches take this device's slots for the wave and return a result
+    of one shape; the per-item store updates are ``execute_schedule``'s,
+    in its order, applied in place after the switch (``_store_tables``).
     """
     from repro.core.modality_parallel import normalize_stage_fns
     prog = program if program is not None else \
@@ -490,18 +539,21 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 state["wg"] = jnp.zeros((L, M) + xshape, xdtype)
                 state["wused"] = jnp.zeros((L, M), jnp.bool_)
 
-            def idle(st, m, i):
-                return st
-
-            def add_grads(st, s, c, gp):
+            def zeros_out():
+                """The result every branch returns: ``y`` (a stage
+                output or input cotangent), the loss and weight-grad
+                increments; zeros where the branch produces none (the
+                idle branch, all of it)."""
                 if hetero:
-                    gl = list(st["grads"])
-                    gl[s] = tuple(map(jnp.add, gl[s], gp))
-                    st["grads"] = tuple(gl)
+                    dg = tuple(tuple(jnp.zeros_like(g) for g in gs)
+                               for gs in zgrads)
                 else:
-                    st["grads"] = jax.tree.map(
-                        lambda G, dG: G.at[c].add(dG), st["grads"], gp)
-                return st
+                    dg = jax.tree.map(lambda a: jnp.zeros_like(a[0]), lp)
+                return (jnp.zeros(xshape, xdtype),
+                        jnp.zeros((), loss_dtype), dg)
+
+            def idle(a, b, m):
+                return zeros_out()
 
             def weight_grads(s, lpc, x, mb, g):
                 """The stage's weight VJP: over the held leaves only
@@ -519,82 +571,53 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 _, vjp_p = jax.vjp(f, *[leaves[i] for i in held[s]])
                 return vjp_p(g)
 
-            def make_branch(kind, s):
-                # device/chunk are static per stage; the microbatch and
-                # item index are traced (rolled table lookups)
-                dev, c = device_of[s], chunk_of[s]
+            def make_branch(kind, s, park):
+                # a branch sees one microbatch's slices, never the
+                # stores: a conditional copies every large operand it
+                # is handed. ``a`` is F's input, or B's and W's stored
+                # input; ``b`` is B's and W's cotangent. Each operand
+                # and result is live while the branch runs, so the
+                # microbatch is read in here and the sink's loss
+                # cotangent is made after the switch
+                c = chunk_of[s]
                 stg = graph.stages[s]
                 prs, sucs = preds[s], succs[s]
 
                 @jax.named_scope(f"stage{s}")
                 @jax.named_scope(kind)
-                def br(st, m, i):
-                    st = dict(st)
+                def br(a, b, m):
+                    y, dloss, dg = zeros_out()
+                    mb = mbs[m]
                     if hetero:
                         lpc = params_t[s]
                     else:
-                        lpc = jax.tree.map(lambda a: a[c], lp)
-                    mb = mbs[m]
+                        lpc = jax.tree.map(lambda t: t[c], lp)
+                    glued = trainable[s] and (kind == "W" or (
+                        kind == "B" and not park))
                     if kind == "F":
-                        x = st["inbox"][c, m] if prs else mb
-                        st["x"] = st["x"].at[c, m].set(x)
-                        st["used"] = st["used"].at[c, m].set(True)
-                        y = fns[s](lpc, x, mb)
-                        if not sucs:             # sink: loss + cotangent
-                            st["out"] = st["out"].at[m].add(y)
-                            st["loss"] = st["loss"] + loss_fn(y)
-                            st["cot"] = st["cot"].at[c, m].add(
-                                jax.grad(loss_fn)(y))
+                        y = fns[s](lpc, a, mb)
+                        if not sucs:             # sink: the loss
+                            dloss = loss_fn(y)
+                    elif kind == "B" and stg.bwd_b > 0 and prs:
+                        _, vjp_x = jax.vjp(
+                            lambda xx: fns[s](lpc, xx, mb), a)
+                        (y,) = vjp_x(b)
+                    if glued:                    # weight grads now
+                        gp = weight_grads(s, lpc, a, mb, b)
+                        if hetero:
+                            dg = tuple(tuple(gp) if q == s else dg[q]
+                                       for q in range(S))
                         else:
-                            st["fy"] = y
-                            for q in sucs:
-                                if device_of[q] == dev:
-                                    st["inbox"] = st["inbox"].at[
-                                        chunk_of[q], m].add(y)
-                    elif kind == "B":
-                        x = st["x"][c, m]
-                        st["used"] = st["used"].at[c, m].set(False)
-                        g = st["cot"][c, m]
-                        st["cot"] = st["cot"].at[c, m].set(
-                            jnp.zeros(xshape, xdtype))
-                        if stg.bwd_b > 0 and prs:
-                            _, vjp_x = jax.vjp(
-                                lambda xx: fns[s](lpc, xx, mb), x)
-                            (dx,) = vjp_x(g)
-                            st["bg"] = dx
-                            for p in prs:
-                                if device_of[p] == dev:
-                                    st["cot"] = st["cot"].at[
-                                        chunk_of[p], m].add(dx)
-                        if trainable[s]:
-                            # park for a deferred W only if the schedule
-                            # emitted one; a trainable stage the cost
-                            # model sees as weight-free glues here
-                            if has_w and stg.bwd_w > 0:
-                                st["wx"] = st["wx"].at[c, m].set(x)
-                                st["wg"] = st["wg"].at[c, m].set(g)
-                                st["wused"] = st["wused"].at[
-                                    c, m].set(True)
-                            else:                # glued: weight grads now
-                                st = add_grads(st, s, c, weight_grads(
-                                    s, lpc, x, mb, g))
-                    else:                        # W
-                        x = st["wx"][c, m]
-                        g = st["wg"][c, m]
-                        st["wused"] = st["wused"].at[c, m].set(False)
-                        if trainable[s]:
-                            st = add_grads(st, s, c, weight_grads(
-                                s, lpc, x, mb, g))
-                    st["occ"] = st["occ"].at[i].set(
-                        jnp.sum(st["used"]).astype(jnp.int32))
-                    if has_w:
-                        st["wocc"] = st["wocc"].at[i].set(
-                            jnp.sum(st["wused"]).astype(jnp.int32))
-                    return st
+                            dg = gp
+                    return y, dloss, dg
                 return br
 
             keys, instr, m_tab, item_tab, R, comm = _rolled_tables(prog)
-            branches = [idle] + [make_branch(k, s) for k, s in keys]
+            ops = _store_tables(prog, keys, trainable)
+            branches = [idle] + [make_branch(kind, s, bool(park))
+                                 for (kind, s), park in
+                                 zip(keys, ops["park"][1:])]
+            tab = {n: jnp.asarray(v) for n, v in ops.items()}
             instr_a = jnp.asarray(instr)
             m_a = jnp.asarray(m_tab)
             item_a = jnp.asarray(item_tab)
@@ -624,8 +647,60 @@ def build_spmd_runner(stage_fn, graph: PipelineGraph,
                 return lax.fori_loop(0, R, round_body, st)
 
             def wave_body(w, st):
-                st = lax.switch(instr_a[w, idx], branches, st,
-                                m_a[w, idx], item_a[w, idx])
+                st = dict(st)
+                k, m, i = instr_a[w, idx], m_a[w, idx], item_a[w, idx]
+                op = {n: v[k] for n, v in tab.items()}
+                c = op["c"]
+
+                def put(name, at, on, v):
+                    # one slot of a store, in place: ``v`` where ``on``
+                    st[name] = st[name].at[at].set(
+                        jnp.where(on, v, st[name][at]))
+
+                with jax.named_scope("store"):
+                    a = jnp.where(op["F"],
+                                  jnp.where(op["src"], mbs[m],
+                                            st["inbox"][c, m]),
+                                  st["x"][c, m])
+                    b = st["cot"][c, m]
+                    if has_w:
+                        a = jnp.where(op["W"], st["wx"][c, m], a)
+                        b = jnp.where(op["W"], st["wg"][c, m], b)
+                y, dloss, dg = lax.switch(k, branches, a, b, m)
+                # execute_schedule's per-item updates, in its order
+                zy = jnp.zeros_like(y)
+                with jax.named_scope("store"):
+                    put("x", (c, m), op["F"], a)
+                    put("used", (c, m), op["F"] | op["B"], op["F"])
+                    st["out"] = st["out"].at[m].add(
+                        jnp.where(op["sink"], y, zy))
+                    st["loss"] = st["loss"] + dloss
+                    gy = jnp.where(op["sink"], jax.grad(loss_fn)(y), zy)
+                    st["cot"] = st["cot"].at[c, m].set(
+                        jnp.where(op["B"], zy, st["cot"][c, m] + gy))
+                    st["fy"] = jnp.where(op["fy"], y, st["fy"])
+                    st["bg"] = jnp.where(op["bg"], y, st["bg"])
+                    for j in range(ops["to_c"].shape[1]):
+                        on, cj = op["to_on"][j], op["to_c"][j]
+                        st["inbox"] = st["inbox"].at[cj, m].add(
+                            jnp.where(on & op["F"], y, zy))
+                        st["cot"] = st["cot"].at[cj, m].add(
+                            jnp.where(on & op["B"], y, zy))
+                    if has_w:
+                        put("wx", (c, m), op["park"], a)
+                        put("wg", (c, m), op["park"], b)
+                        put("wused", (c, m), op["park"] | op["W"],
+                            op["park"])
+                if hetero:
+                    st["grads"] = jax.tree.map(jnp.add, st["grads"], dg)
+                else:
+                    st["grads"] = jax.tree.map(
+                        lambda G, d: G.at[c].add(d), st["grads"], dg)
+                live = op["F"] | op["B"] | op["W"]
+                put("occ", i, live, jnp.sum(st["used"]).astype(jnp.int32))
+                if has_w:
+                    put("wocc", i, live,
+                        jnp.sum(st["wused"]).astype(jnp.int32))
                 if R:
                     st = comm_rounds(w, st)
                 return st

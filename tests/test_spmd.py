@@ -23,6 +23,7 @@ from repro.core.modality_parallel import execute_schedule
 from repro.core.schedule.graph import PipelineGraph
 from repro.core.schedule.memory import (MemoryModelMismatch,
                                         validate_schedule_memory)
+from repro.core.schedule.simulator import run_schedule
 from repro.parallel.spmd import (compile_spmd_program, default_mesh,
                                  reference_dag_loss, run_schedule_spmd,
                                  toy_stage_model)
@@ -121,23 +122,34 @@ def test_spmd_frozen_prefix_zero_grads(schedule):
 # fan-in DAG (modality parallelism)
 # ---------------------------------------------------------------------------
 
-def fanin_dag():
+def fanin_dag(train_encoders: bool = False):
     """Two frozen encoders fan into a 2-stage trainable LLM — the
-    modality-parallel shape where two devices' outputs land on one."""
-    stages = [sch.Stage("enc0", 1.0, 1.0, bwd_w=0.0),
-              sch.Stage("enc1", 1.2, 1.2, bwd_w=0.0),
+    modality-parallel shape where two devices' outputs land on one.
+    ``train_encoders`` gives the encoders weight grads, so their
+    cotangents must arrive."""
+    w = 1.0 if train_encoders else 0.0
+    stages = [sch.Stage("enc0", 1.0, 1.0 + w, bwd_w=w),
+              sch.Stage("enc1", 1.2, 1.2 + w, bwd_w=w),
               sch.Stage("llm", 1.0, 2.0, bwd_w=1.0),
               sch.Stage("llm", 1.0, 2.0, bwd_w=1.0)]
     return PipelineGraph(stages, [(0, 2), (1, 2), (2, 3)])
 
 
-@pytest.mark.parametrize("schedule", ["1f1b", "zb-h1"])
+@pytest.mark.parametrize("schedule", ["1f1b", "zb-h1", "colocated"])
 @subprocess_test(4)
 def test_spmd_fanin_dag_matches_replay_and_autodiff(schedule):
     """Non-chain DAG: the cotangent fan-in merge must reproduce both
-    the generalized replay and the single-device autodiff oracle."""
-    g = fanin_dag()
-    sim = sch.get_scheduler(schedule).simulate(g, 6)
+    the generalized replay and the single-device autodiff oracle.
+    ``colocated`` puts both encoders, trained here, on the first LLM
+    stage's device, with split W passes: that stage's B hands its input
+    cotangent to two local predecessors, and both encoders' F sum into
+    its inbox on the device itself."""
+    g = fanin_dag(train_encoders=schedule == "colocated")
+    if schedule == "colocated":
+        sim = run_schedule(g, 6, device_of=[0, 0, 0, 1], split_bw=True)
+        assert any(it[3] == "W" for it in sim["items"])
+    else:
+        sim = sch.get_scheduler(schedule).simulate(g, 6)
     fn, params = toy_stage_model(4, 8)
     mbs = jax.random.normal(jax.random.PRNGKey(2), (6, 1, 4, 8))
     ref = execute_schedule(fn, params, mbs, g, sim)
@@ -150,8 +162,13 @@ def test_spmd_fanin_dag_matches_replay_and_autodiff(schedule):
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
         got["param_grads"], oracle_grads)
-    # frozen encoders: zero grads, exactly
-    assert not np.asarray(got["param_grads"]["w"][:2]).any()
+    if schedule == "colocated":
+        # both local cotangents arrived: every encoder trained
+        assert all(np.asarray(got["param_grads"]["w"][s]).any()
+                   for s in range(2))
+    else:
+        # frozen encoders: zero grads, exactly
+        assert not np.asarray(got["param_grads"]["w"][:2]).any()
 
 
 # ---------------------------------------------------------------------------

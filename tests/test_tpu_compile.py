@@ -6,7 +6,9 @@ Hkv=8, hd=128; T=4096, and T=1600 padded; and the pipeline cell's
 H=28, Hkv=4 at T=1600). Nothing runs: Mosaic
 compiles each kernel for a chip that is described, not attached, and
 refuses what the chip would refuse (block tiling, VMEM budget) — which
-interpret mode never does.
+interpret mode never does. The SPMD pipeline runner is compiled the same
+way, on a mesh of described chips, and its program read for copies of
+its activation stores.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU
@@ -14,6 +16,7 @@ compiler's library, and the test workers import every test file.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -150,3 +153,45 @@ def test_training_kernel_compiles_for_v5e(case, cell, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem is not None and np.isfinite(mem.temp_size_in_bytes)
+
+
+def test_spmd_runner_copies_no_store_for_v5e(topo, no_compilation_cache):
+    """The rolled SPMD runner, compiled for described v5e chips at the
+    reduced paper MLLM's plan, holds no copy of an ``[L, M]`` activation
+    store, and none of an ``[M]`` buffer (the outputs, the microbatches)
+    outside the entry computation, where the compiler may lay the
+    microbatches out once a call: its ``lax.switch`` branches see one
+    microbatch's slices, and the stores are updated in place in the
+    wave loop under ``store``. Handing the stores to the switch makes
+    XLA copy each in every branch and again in the loop body."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.parallel.spmd import build_spmd_runner
+    from .test_spmd_train import M, tiny_batch, tiny_case
+    mllm, _plan, ex = tiny_case("paper")
+    bundle = ex["stage_bundle"]
+    D = int(ex["schedule"]["num_devices"])
+    mesh = Mesh(np.array(topo.devices[:D]), ("pp",))
+    runner = build_spmd_runner(
+        bundle.stage_fns, ex["sim_graph"], ex["schedule"], mesh=mesh,
+        microbatch_loss=bundle.microbatch_loss, program=ex["spmd_program"],
+        trainable=list(bundle.trainable))
+    rep = NamedSharding(mesh, P())
+
+    def s(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+    sp = jax.eval_shape(lambda k: bundle.partition(mllm.init(k)),
+                        jax.random.PRNGKey(0))
+    mbs = s(jax.eval_shape(
+        lambda: bundle.encode_microbatches(tiny_batch(mllm), M)))
+    text = runner.core.lower(tuple(jax.tree.map(s, sp)), mbs,
+                             hetero=True).compile().as_text()
+    xshape = mbs.shape[1:]
+    entry = text.index("\nENTRY ")
+    loop = text[:entry] + text[text.index("\n}", entry):]
+    for shape, where in (((runner.program.max_chunks, M) + xshape, text),
+                         ((M,) + xshape, loop)):
+        dims = ",".join(map(str, shape))
+        pat = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+        assert not [ln for ln in where.splitlines() if pat.search(ln)], \
+            shape
+    assert "all-gather" in text and "/store/" in text

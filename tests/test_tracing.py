@@ -206,7 +206,7 @@ def test_kernel_attention_runs_under_sdpa():
 #: the pipeline runner's own scopes, and the model scopes its stage fns
 #: carry as the one-chip step does
 SPMD_SCOPES = ("handoff", "pipeline_reduce", "encoder", "projector", "llm",
-               "lm_head", "attention", "sdpa", "mlp")
+               "lm_head", "attention", "sdpa", "mlp", "store")
 
 _SPMD_CHILD = r'''
 import json
